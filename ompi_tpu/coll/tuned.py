@@ -65,7 +65,7 @@ def _load_rules(path: str) -> Dict[str, Dict]:
     return rules
 
 
-# -- probe-earned staging threshold (VERDICT r4 next #3) ---------------
+# -- probe-earned staging threshold ---------------
 # The r4 run of record staged 8 MB allreduces onto a tier its own A/B
 # showed 1.6x slower, because the switch point was a data-blind 1 MB
 # constant. Like the bml's bulk routing, the threshold now earns its
@@ -143,7 +143,7 @@ def staging_probe(transport_bps: Optional[float] = None,
     else:
         n_star = (a_s - a_h) / (b_h - b_s)
         cross = int(min(max(n_star, 64 << 10), _NEVER_STAGE))
-    # Close the staging contract (VERDICT r5 next #3): the two-point
+    # Close the staging contract: the two-point
     # fit EXTRAPOLATES, and the round-5 record routed 8 MB to a tier
     # its own A/B measured 1.3x slower because the fitted crossover
     # landed just under the payload. Confirm by MEASUREMENT at the

@@ -136,7 +136,7 @@ class XhcComponent(Component):
         else:
             sizes = locality_sizes(comm.devices)
             if sizes is None:
-                # the hwloc-depth walk (VERDICT r4 next #10): OS
+                # the hwloc-depth walk: OS
                 # topology levels, else a labeled synthetic
                 # factorization so the ladder still has depth on flat
                 # virtual meshes

@@ -43,11 +43,6 @@ from ompi_tpu.coll.framework import coll_framework
 from ompi_tpu.mca import var
 from ompi_tpu.mca.base import Component
 
-try:                                    # jax >= 0.4.35 public API
-    _shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 P = jax.sharding.PartitionSpec
 
 _ARITH_KINDS = frozenset("fiuc")        # dtypes psum/pmax/pmin accept
@@ -117,10 +112,7 @@ class XlaCollModule:
             try:
                 fn = build()
                 if lower_args:
-                    try:
-                        fn = fn.lower(*lower_args).compile()
-                    except Exception:   # fall back to the jit wrapper
-                        pass
+                    fn = fn.lower(*lower_args).compile()
             finally:
                 if tok is not None:
                     _trace.end(tok)
@@ -128,8 +120,9 @@ class XlaCollModule:
         return fn
 
     def _smap(self, inner: Callable, ndim_in: int, ndim_out: int) -> Callable:
-        f = _shard_map(inner, mesh=self.comm.mesh,
-                       in_specs=_spec(ndim_in), out_specs=_spec(ndim_out))
+        f = jax.shard_map(inner, mesh=self.comm.mesh,
+                          in_specs=_spec(ndim_in),
+                          out_specs=_spec(ndim_out))
         return jax.jit(f)
 
     def _to_mesh(self, x):
@@ -509,7 +502,7 @@ class XlaCollModule:
         the program orders segment s+1's collective-permutes after
         segment s's combines (round 2 unrolled segments *inside* each
         ring step, whose scan carry re-serialized them at every step
-        boundary; that version lost its own A/B, VERDICT r2 weak #1).
+        boundary; that version lost its own A/B).
 
         Measured (BENCH_r03 ab_matrix, 8-rank host mesh): the
         independent-chain restructure beats the plain ring at every
@@ -1077,7 +1070,7 @@ class XlaCollModule:
             return x
         return inner
 
-    # -- root-targeted schedules (VERDICT round-2 #3) --------------------
+    # -- root-targeted schedules --------------------
     # XLA's ppermute moves bytes only along the listed (src, dst) pairs,
     # so binomial trees rooted at `root` are expressible in-graph: wire
     # traffic is root-directed even though SPMD shapes stay uniform.
@@ -1089,6 +1082,13 @@ class XlaCollModule:
         while p < n:
             p *= 2
         return p
+
+    @staticmethod
+    def _at(i, ndim: int) -> tuple:
+        """Start index ``(i, 0, ..., 0)`` with every entry in ``i``'s
+        dtype: under x64 a literal 0 is int64 beside axis_index's int32,
+        and dynamic_(update_)slice refuses mixed index dtypes."""
+        return (i,) + (jnp.zeros((), i.dtype),) * ndim
 
     def _rabenseifner_root_reduce_inner(self, n, root, shape):
         """reduce = psum_scatter (each rank reduces 1/n) + binomial
@@ -1113,15 +1113,16 @@ class XlaCollModule:
             r = jax.lax.axis_index(AXIS)
             v = jnp.mod(r - root, n)
             buf = jnp.zeros((npad, chunk), part.dtype)
-            buf = jax.lax.dynamic_update_slice(buf, part, (v, 0))
+            buf = jax.lax.dynamic_update_slice(buf, part, self._at(v, 1))
             d = 1
             while d < npad:
                 perm = [((vs + root) % n, (vs - d + root) % n)
                         for vs in range(d, n, 2 * d)]
                 send = jax.lax.dynamic_slice(
-                    buf, (jnp.minimum(v, npad - d), 0), (d, chunk))
+                    buf, self._at(jnp.minimum(v, npad - d), 1), (d, chunk))
                 recvd = jax.lax.ppermute(send, AXIS, perm=perm)
-                upd = jax.lax.dynamic_update_slice(buf, recvd, (v + d, 0))
+                upd = jax.lax.dynamic_update_slice(buf, recvd,
+                                                   self._at(v + d, 1))
                 buf = jnp.where(jnp.mod(v, 2 * d) == 0, upd, buf)
                 d *= 2
             res = buf[:n].reshape(-1)[:total]
@@ -1142,18 +1143,18 @@ class XlaCollModule:
             r = jax.lax.axis_index(AXIS)
             v = jnp.mod(r - root, n)
             buf = jnp.zeros((npad,) + x.shape, x.dtype)
-            start0 = (v,) + (0,) * x.ndim
-            buf = jax.lax.dynamic_update_slice(buf, x[None], start0)
+            buf = jax.lax.dynamic_update_slice(buf, x[None],
+                                               self._at(v, x.ndim))
             d = 1
             while d < npad:
                 perm = [((vs + root) % n, (vs - d + root) % n)
                         for vs in range(d, n, 2 * d)]
                 send = jax.lax.dynamic_slice(
-                    buf, (jnp.minimum(v, npad - d),) + (0,) * x.ndim,
+                    buf, self._at(jnp.minimum(v, npad - d), x.ndim),
                     (d,) + x.shape)
                 recvd = jax.lax.ppermute(send, AXIS, perm=perm)
                 upd = jax.lax.dynamic_update_slice(
-                    buf, recvd, (v + d,) + (0,) * x.ndim)
+                    buf, recvd, self._at(v + d, x.ndim))
                 buf = jnp.where(jnp.mod(v, 2 * d) == 0, upd, buf)
                 d *= 2
             idx = jnp.mod(jnp.arange(n) - root, n)    # vrank -> rank rows
@@ -1183,15 +1184,14 @@ class XlaCollModule:
                 perm = [((vs + root) % n, (vs + d + root) % n)
                         for vs in range(0, n, 2 * d) if vs + d < n]
                 send = jax.lax.dynamic_slice(
-                    buf, (jnp.minimum(v + d, npad - d),) + (0,) * len(s),
+                    buf, self._at(jnp.minimum(v + d, npad - d), len(s)),
                     (d,) + s)
                 recvd = jax.lax.ppermute(send, AXIS, perm=perm)
                 upd = jax.lax.dynamic_update_slice(
-                    buf, recvd, (v,) + (0,) * len(s))
+                    buf, recvd, self._at(v, len(s)))
                 buf = jnp.where(jnp.mod(v, 2 * d) == d, upd, buf)
                 d //= 2
-            own = jax.lax.dynamic_slice(
-                buf, (v,) + (0,) * len(s), (1,) + s)
+            own = jax.lax.dynamic_slice(buf, self._at(v, len(s)), (1,) + s)
             return own                   # (1, *s)
         return inner
 
@@ -1271,7 +1271,7 @@ class XlaCollModule:
         a compile-time constant. Replaces the 3-dispatch
         pack/collective/unpack chain whose per-call index H2D and
         extra SPMD launches made a strided allreduce 6x the contiguous
-        one (VERDICT r4 weak #6). ``preserve_gaps``: scatter into the
+        one. ``preserve_gaps``: scatter into the
         input (IN_PLACE recvbuf semantics) vs a zeroed image (the
         functional no-recvbuf contract). Reference for the semantics:
         opal_convertor.c:83-102 (only significant bytes travel)."""
@@ -1620,7 +1620,7 @@ class XlaCollModule:
         # staged once per (communicator, algorithm) so the per-call cost
         # is one dispatch of a pre-compiled scalar collective. Round 1
         # allocated jnp.ones + device_put on every call, which put two
-        # host->device transfers on the hot path (VERDICT.md weak #2).
+        # host->device transfers on the hot path.
         alg = self._algorithm("barrier", 4)
         low = high = None
         if alg == "hier":

@@ -33,11 +33,7 @@ from __future__ import annotations
 from typing import Any, Dict, Tuple
 
 import numpy as np
-
-try:                                     # fp8 needs ml_dtypes (jax dep)
-    from ml_dtypes import float8_e4m3fn as _f8
-except ImportError:                      # pragma: no cover
-    _f8 = None
+from ml_dtypes import float8_e4m3fn as _f8
 
 DEFAULT_BLOCK = 256
 
@@ -188,8 +184,6 @@ class Fp8BlockCodec(Codec):
     name = "fp8_block"
 
     def encode(self, arr, block=DEFAULT_BLOCK):
-        if _f8 is None:                  # pragma: no cover
-            raise RuntimeError("fp8_block codec needs ml_dtypes")
         flat = np.ascontiguousarray(arr, dtype=np.float32).reshape(-1)
         blocks, _pad = _pad_blocks(flat, block)
         maxabs = np.abs(blocks).max(axis=1)
@@ -210,8 +204,6 @@ class Fp8BlockCodec(Codec):
         return codes.reshape(-1).view(np.int8), scales
 
     def decode(self, codes, scales, shape, dtype, block=DEFAULT_BLOCK):
-        if _f8 is None:                  # pragma: no cover
-            raise RuntimeError("fp8_block codec needs ml_dtypes")
         scales = np.asarray(scales, np.float32)
         out = np.asarray(codes, np.int8).view(_f8) \
             .astype(np.float32).reshape(len(scales), block)
@@ -254,9 +246,8 @@ class Fp8BlockCodec(Codec):
 _REGISTRY: Dict[str, Codec] = {
     "null": NullCodec(),
     "int8_block": Int8BlockCodec(),
+    "fp8_block": Fp8BlockCodec(),
 }
-if _f8 is not None:
-    _REGISTRY["fp8_block"] = Fp8BlockCodec()
 
 
 def get_codec(name: str) -> Codec:

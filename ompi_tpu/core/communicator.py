@@ -305,7 +305,7 @@ class Communicator:
             return fn(sendbuf, op)
         self._validate_stacked(sendbuf)
         self._validate_op(op)
-        # Fused derived-datatype fast path (VERDICT r4 weak #6): one
+        # Fused derived-datatype fast path: one
         # compiled gather->collective->scatter program instead of the
         # pack/collective/unpack dispatch chain. Device buffers only
         # (host buffers keep the convertor path); a DISTINCT recvbuf's
@@ -386,7 +386,7 @@ class Communicator:
         rank root's recvbuf, an (N, *local) array resident ONLY on
         root's device. Non-root devices allocate nothing — vs the
         in-graph gather, whose uniform SPMD output holds N rows on
-        every device (the round-1 n-times-memory cost VERDICT flagged).
+        every device (the round-1 n-times-memory cost).
         The collect is a runtime D2D transfer over ICI: PJRT moves each
         shard straight to root (the binomial-gather role,
         coll_base_functions.h:185-320, with the tree supplied by the
@@ -456,7 +456,7 @@ class Communicator:
         arrays (the variable-length result cannot be one stacked
         array).
 
-        Round-2 lowering (VERDICT weak #6): segments are padded to the
+        Round-2 lowering: segments are padded to the
         max count with ONE device gather (a static index map built from
         the counts), then ride ``reduce_scatter_block`` — psum_scatter
         on the device path — so the wire moves ~N*max(counts) elements
@@ -515,7 +515,7 @@ class Communicator:
     # collective over ICI, slice the valid prefixes off on the way out —
     # the TPU analogue of the reference's per-peer count headers
     # (ompi/mca/coll/base alltoallv/allgatherv pairwise exchanges).
-    # Round 2 (VERDICT weak #5): device inputs are padded ON DEVICE and
+    # Round 2: device inputs are padded ON DEVICE and
     # results come back as device arrays (lazy slices of the collective
     # output) — the round-1 implementation round-tripped everything
     # through NumPy, the opposite of the framework's thesis.

@@ -20,6 +20,7 @@ from __future__ import annotations
 import itertools
 from typing import List, Optional, Sequence, Tuple
 
+import ml_dtypes
 import numpy as np
 
 
@@ -195,7 +196,7 @@ class Datatype:
     def flat_indices(self, count: int) -> np.ndarray:
         """Flat element indices for ``count`` consecutive instances —
         cached per instance (rebuilt index maps were a measured tax on
-        the derived-datatype hot path, VERDICT r4 weak #6)."""
+        the derived-datatype hot path)."""
         got = self._flat_cache.get(count)
         if got is None:
             got = (np.arange(count)[:, None] * self.extent
@@ -217,11 +218,7 @@ def _predef(np_dtype, name: str, pair: bool = False) -> Datatype:
 FLOAT = _predef(np.float32, "float")
 DOUBLE = _predef(np.float64, "double")
 FLOAT16 = _predef(np.float16, "float16")
-try:
-    import ml_dtypes
-    BFLOAT16 = _predef(ml_dtypes.bfloat16, "bfloat16")
-except ImportError:                                    # pragma: no cover
-    BFLOAT16 = _predef(np.float16, "bfloat16")
+BFLOAT16 = _predef(ml_dtypes.bfloat16, "bfloat16")
 INT = _predef(np.int32, "int")
 LONG = _predef(np.int64, "long")
 SHORT = _predef(np.int16, "short")

@@ -114,10 +114,10 @@ def _rmsnorm(x, g):
 
 
 def _flash_causal(q, k, v, cfg: Config):
-    """Single-block causal attention through the flash kernel
-    (ops/flash_attention): mode 1 is exactly the causal diagonal
-    block. Pallas on TPU, the same-math jnp fold elsewhere."""
-    from ompi_tpu.ops.flash_attention import flash_block_update
+    """Single-block causal attention through the flash online-softmax
+    fold (ops/flash_attention): mode 1 is exactly the causal diagonal
+    block."""
+    from ompi_tpu.ops.flash_attention import fold_jnp
     B, S, H, D = q.shape
     scale = jnp.asarray(cfg.d_head, jnp.float32) ** -0.5
     qf = (jnp.transpose(q, (0, 2, 1, 3)).reshape(B * H, S, D)
@@ -129,11 +129,9 @@ def _flash_causal(q, k, v, cfg: Config):
     o = jnp.zeros_like(qf)
     m = jnp.full((B * H, S), -1e30, jnp.float32)
     l = jnp.zeros((B * H, S), jnp.float32)
-    # the TRAINING path needs AD: the jnp online-softmax fold is the
-    # same flash math, differentiable and XLA-fused; the pallas kernel
-    # (no VJP yet) serves forward-only uses
-    o, m, l = flash_block_update(qf, kf, vf, o, m, l, 1,
-                                 use_pallas=False)
+    # the TRAINING path needs AD: the jnp fold is the kernel's math,
+    # differentiable and XLA-fused (the Pallas kernel has no VJP)
+    o, m, l = fold_jnp(qf, kf, vf, o, m, l, 1)
     o = o / jnp.where(l == 0.0, 1.0, l)[..., None]
     return jnp.transpose(o.reshape(B, H, S, D),
                          (0, 2, 1, 3)).astype(q.dtype)
@@ -380,10 +378,16 @@ def sgd_train_step(params, batch, cfg: Config, lr: float,
     ``grad_sync`` replaces the in-graph dp pmean with DDP-style
     bucketed persistent allreduces over the framework's communicator
     tier (one fused wire collective per gradient bucket instead of one
-    collective per tensor — docs/PERSISTENT.md)."""
+    collective per tensor — docs/PERSISTENT.md). On the stacked
+    single-controller tier ``params`` and ``batch`` lead with the rank
+    axis, one data-parallel replica per rank, and the returned loss is
+    per rank."""
     inputs, targets = batch
-    loss, grads = jax.value_and_grad(loss_fn)(params, inputs, targets,
-                                              cfg, tp_comm, sp_comm)
+    grad_fn = jax.value_and_grad(
+        lambda p, i, t: loss_fn(p, i, t, cfg, tp_comm, sp_comm))
+    if grad_sync is not None and grad_sync.stacked:
+        grad_fn = jax.vmap(grad_fn)
+    loss, grads = grad_fn(params, inputs, targets)
     for comm in (sp_comm, dp_comm if grad_sync is None else None):
         if comm is not None:
             grads = jax.tree_util.tree_map(lambda g: comm.pmean(g), grads)
@@ -414,6 +418,7 @@ class BucketedGradSync:
         from ompi_tpu.core import op as _op
         self.comm = comm
         self.n = comm.size
+        self.stacked = not getattr(comm, "is_per_rank", False)
         leaves, self._treedef = jax.tree_util.tree_flatten(grads_example)
         self._stages = [np.zeros(tuple(g.shape),
                                  np.dtype(jnp.asarray(g).dtype))
@@ -455,14 +460,14 @@ class BucketedGradSync:
 
     def mean_scalar(self, value):
         """Mean one scalar (the loss) over the comm — rides the same
-        persistent machinery through a lazily-built 1-elem plan."""
+        persistent machinery through a lazily-built 1-elem plan. On the
+        stacked tier ``value`` is one scalar for every rank or a
+        per-rank vector."""
         import numpy as np
         from ompi_tpu.core import op as _op
         if self._scalar_req is None:
-            shape = tuple(np.shape(value)) or ()
             self._scalar_stage = np.zeros(
-                (self.n,) + shape if not getattr(
-                    self.comm, "is_per_rank", False) else shape,
+                (self.n,) if self.stacked else np.shape(value),
                 np.float64)
             self._scalar_req = self.comm.allreduce_init(
                 self._scalar_stage, _op.SUM)

@@ -9,9 +9,11 @@ flash accumulators (o, m, l). This module owns that fold:
   online-softmax update without ever materializing the (S, S) score
   matrix in HBM — the memory behavior flash attention exists for
   (HBM-bandwidth note in SURVEY §"Design for TPU").
-- ``flash_block_update`` — the public entry: dispatches to the kernel
-  when Pallas can run (TPU, aligned shapes; ``interpret=True`` runs the
-  same kernel on CPU for tests), else to the identical jnp fold.
+- ``flash_block_update`` — the kernel's public entry. It refuses shapes
+  Mosaic cannot tile; ``interpret=True`` runs the same kernel on CPU for
+  tests.
+- ``fold_jnp`` — the same online-softmax math in jnp: the kernel's
+  numerical oracle, and the differentiable path training asks for.
 
 Mask ``mode`` (traced scalar, SMEM): 0 = attend fully (earlier ring
 block), 1 = causal diagonal (the resident block), 2 = fully masked
@@ -26,24 +28,15 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 _NEG = -1e30
-
-
-def pallas_available() -> bool:
-    try:
-        from jax.experimental import pallas as pl          # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu   # noqa: F401
-        return True
-    except ImportError:
-        return False
+_F32_DOT = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------
-# the jnp fold (fallback + numerical oracle for the kernel tests)
+# the jnp fold (training path + numerical oracle for the kernel tests)
 # ---------------------------------------------------------------------
-def _fold_jnp(q, k, v, o, m, l, mode):
+def fold_jnp(q, k, v, o, m, l, mode):
     """q: (BH, Sq, D) pre-scaled; k/v: (BH, Sk, D); o: (BH, Sq, D);
     m/l: (BH, Sq); mode: scalar int32."""
     s = jnp.einsum("bqd,bkd->bqk", q, k)
@@ -92,19 +85,22 @@ def _block_kernel(mode_ref, q_ref, k_ref, v_ref, oi_ref, mi_ref, li_ref,
     ks = k_ref[0].astype(jnp.float32)         # (bk, D)
     vs = v_ref[0].astype(jnp.float32)
     o, m, l = o_acc[...], m_acc[...], l_acc[...]
-    s = jnp.dot(q, ks.T, preferred_element_type=jnp.float32)
+    # fp32 contraction: Mosaic's default for f32 operands is a reduced
+    # bf16 precision (1e-3 off the f32 fold on a v5e at S=2048)
+    s = jnp.dot(q, ks.T, precision=_F32_DOT,
+                preferred_element_type=jnp.float32)
     row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     col = kt * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     # boolean algebra (a scalar-condition select does not legalize
     # in Mosaic): full -> all, diag -> lower triangle, else none
     allow = (mode == 0) | ((mode == 1) & (row >= col))
-    s = jnp.where(allow, s, _NEG)
+    s = jnp.where(allow, s, jnp.float32(_NEG))   # f32 under x64 too
     m_new = jnp.maximum(m, s.max(axis=-1)[:, None])       # replicated
     p = jnp.exp(s - m_new[:, 0:1])
     corr = jnp.exp(m - m_new)                             # replicated
     l_new = l * corr + p.sum(axis=-1)[:, None]
     o_new = o * corr[:, 0:1] + jnp.dot(
-        p, vs, preferred_element_type=jnp.float32)
+        p, vs, precision=_F32_DOT, preferred_element_type=jnp.float32)
     o_acc[...], m_acc[...], l_acc[...] = o_new, m_new, l_new
 
     @pl.when(kt == nk - 1)
@@ -112,6 +108,12 @@ def _block_kernel(mode_ref, q_ref, k_ref, v_ref, oi_ref, mi_ref, li_ref,
         oo_ref[0] = o_acc[...]
         mo_ref[0] = m_acc[...]
         lo_ref[0] = l_acc[...]
+
+
+def _i32(*idx):
+    """Block indices as int32: under x64 a literal 0 traces as int64,
+    which Mosaic cannot return from an index map."""
+    return tuple(jnp.asarray(i, jnp.int32) for i in idx)
 
 
 @functools.partial(jax.jit,
@@ -131,23 +133,15 @@ def _pallas_fold(q, k, v, o, m, l, mode, *, bq: int, bk: int,
     m3 = jnp.broadcast_to(m[..., None], (BH, Sq, _LANES))
     l3 = jnp.broadcast_to(l[..., None], (BH, Sq, _LANES))
 
-    vmem = pltpu.ANY if interpret else pltpu.VMEM
-    qo_spec = pl.BlockSpec((1, bq, D), lambda bh, qi, kt: (bh, qi, 0),
-                           memory_space=vmem)
-    kv_spec = pl.BlockSpec((1, bk, D), lambda bh, qi, kt: (bh, kt, 0),
-                           memory_space=vmem)
+    qo_spec = pl.BlockSpec((1, bq, D), lambda bh, qi, kt: _i32(bh, qi, 0))
+    kv_spec = pl.BlockSpec((1, bk, D), lambda bh, qi, kt: _i32(bh, kt, 0))
     ml_spec = pl.BlockSpec((1, bq, _LANES),
-                           lambda bh, qi, kt: (bh, qi, 0),
-                           memory_space=vmem)
+                           lambda bh, qi, kt: _i32(bh, qi, 0))
     specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                 # mode
+        pl.BlockSpec((1, 1), lambda bh, qi, kt: _i32(0, 0),
+                     memory_space=pltpu.SMEM),                 # mode
         qo_spec, kv_spec, kv_spec, qo_spec, ml_spec, ml_spec,
     ]
-    try:
-        params = dict(compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
-    except Exception:                   # older pallas: no params class
-        params = {}
     oo, mo, lo = pl.pallas_call(
         kern,
         grid=grid,
@@ -164,7 +158,8 @@ def _pallas_fold(q, k, v, o, m, l, mode, *, bq: int, bk: int,
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running denom
         ],
         interpret=interpret,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(mode_arr, q, k, v, o, m3, l3)
     return oo, mo[..., 0], lo[..., 0]
 
@@ -176,27 +171,26 @@ def _tile_sizes(Sq: int, Sk: int) -> Tuple[int, int]:
 
 
 def flash_block_update(q, k, v, o, m, l, mode, *,
-                       use_pallas: bool = True,
-                       interpret: bool | None = None):
-    """Fold one K/V block into the flash accumulators.
+                       interpret: bool = False):
+    """Fold one K/V block into the flash accumulators with the Pallas
+    kernel.
 
     Args (all float32, q pre-scaled):
       q: (BH, Sq, D); k, v: (BH, Sk, D); o: (BH, Sq, D); m, l: (BH, Sq)
       mode: traced int — 0 full, 1 causal diagonal, 2 fully masked
-    Returns (o, m, l) updated.
+      interpret: run the kernel in the Pallas interpreter (CPU tests)
+    Returns (o, m, l) updated. Raises ValueError for a shape Mosaic
+    cannot tile: q/o blocks are (bq, D) and score tiles (bq, bk), whose
+    last two dims must be multiples of (8, 128).
     """
-    BH, Sq, D = q.shape
+    Sq, D = q.shape[1:]
     Sk = k.shape[1]
     bq, bk = _tile_sizes(Sq, Sk)
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    # Mosaic tiling: q/o blocks are (bq, D), score tiles (bq, bk) —
-    # all last-two-dims must be (8k, 128k). Interpret mode (tests) has
-    # no such constraint.
-    aligned = (Sq % bq == 0 and Sk % bk == 0
-               and (interpret or (bq % 8 == 0 and bk % 128 == 0
-                                  and D % 128 == 0)))
-    if not (use_pallas and pallas_available() and aligned):
-        return _fold_jnp(q, k, v, o, m, l, mode)
+    if not (Sq % bq == 0 and Sk % bk == 0 and bq % 8 == 0
+            and bk % 128 == 0 and D % 128 == 0):
+        raise ValueError(
+            f"flash_block_update: Sq={Sq}, Sk={Sk}, D={D} do not tile "
+            f"as ({bq}, {bk}) blocks; need Sq % 8, Sk % 128 and D % 128 "
+            "all zero (use fold_jnp for other shapes)")
     return _pallas_fold(q, k, v, o, m, l, mode,
                         bq=bq, bk=bk, interpret=interpret)

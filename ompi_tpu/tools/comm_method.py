@@ -73,7 +73,7 @@ def table(comm) -> Dict:
         if basis:
             out["btl_probe"] = dict(basis)
     # the staged device tier's measured switch point (same discipline:
-    # the decision shows its data, VERDICT r4 next #3)
+    # the decision shows its data)
     from ompi_tpu.coll.tuned import probed_stage_basis
     sb = probed_stage_basis()
     if sb.get("ran"):

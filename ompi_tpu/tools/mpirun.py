@@ -85,7 +85,11 @@ def _free_port() -> int:
 
 def run_per_rank(args, prog) -> int:
     """Spawn N rank processes (the PRRTE daemon's fork/exec role) and
-    reap them; first nonzero exit aborts the job, as mpirun does."""
+    reap them; first nonzero exit aborts the job, as mpirun does.
+
+    Every rank runs on the host platform: a chip belongs to one process
+    at a time, so N ranks reaching for it would fail or hang. Ranks get
+    their own chip only once a launcher hands each one a device."""
     n = args.n or 2
     coord = args.coordinator or f"127.0.0.1:{_free_port()}"
     procs = []
@@ -97,6 +101,7 @@ def run_per_rank(args, prog) -> int:
                  if p]
         if pkg_root not in parts:
             env["PYTHONPATH"] = os.pathsep.join([pkg_root] + parts)
+        env["JAX_PLATFORMS"] = "cpu"
         env["OMPI_TPU_MCA_mpi_base_distributed"] = "1"
         env["OMPI_TPU_MCA_mpi_base_per_rank"] = "1"
         env["OMPI_TPU_MCA_mpi_base_coordinator"] = coord

@@ -7,7 +7,7 @@ framework (``ompi/mca/topo/``): each rank exchanges buffers with its
 cart/graph neighbors; cart shifts are the halo-exchange workhorse.
 
 TPU-native re-design (round 3 — the round-2 versions were host NumPy
-round-trips, VERDICT weak #6): a neighbor exchange IS a set of
+round-trips): a neighbor exchange IS a set of
 ``ppermute`` patterns. Every (source → dest) topology edge is assigned
 to a *wave* by greedy edge coloring (each wave touches every rank at
 most once as source and once as dest — König: ≤ max-degree waves on the
@@ -119,8 +119,7 @@ def _wave_permute(comm, arr, perm):
     """One wave: a single XLA collective-permute over the mesh axis."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from ompi_tpu.coll.xla import _shard_map
-    return _shard_map(
+    return jax.shard_map(
         lambda a: jax.lax.ppermute(a, AXIS, perm=perm),
         mesh=comm.mesh, in_specs=P(AXIS), out_specs=P(AXIS))(arr)
 

@@ -1,5 +1,4 @@
-"""Host locality synthesis — the hwloc-depth role (VERDICT r4 next
-#10).
+"""Host locality synthesis — the hwloc-depth role.
 
 Behavioral spec: the reference feeds NUMA/socket/L3 levels from hwloc
 to its hierarchical components (``opal/mca/hwloc/base/``; xhc builds

@@ -1,4 +1,4 @@
-/* Derived-datatype closure (VERDICT r4 next #5/#6): the byte-granular
+/* Derived-datatype closure: the byte-granular
  * constructors (hvector/hindexed/struct), subarray, darray, and the
  * lb/extent model — a negative-stride vector round-trips through
  * Send/Recv with elements BEHIND the buffer pointer, the layout the

@@ -1,4 +1,4 @@
-/* Send-mode closure + matched probe + cancel (VERDICT r4 next #5):
+/* Send-mode closure + matched probe + cancel:
  * Issend/Ibsend/Irsend, Bsend/Rsend, Buffer_attach/detach,
  * Mprobe/Improbe/Mrecv/Imrecv, Cancel/Test_cancelled,
  * Status_set_elements/cancelled. References:

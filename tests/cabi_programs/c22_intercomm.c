@@ -1,4 +1,4 @@
-/* Communicator-construction closure (VERDICT r4 next #5):
+/* Communicator-construction closure:
  * Cart_sub (every 2-D decomposition textbook), Intercomm_create /
  * Intercomm_merge, Comm_create_group, Grequest_start/complete.
  * References: ompi/mpi/c/cart_sub.c.in, intercomm_create.c.in,

@@ -1,4 +1,4 @@
-/* MPI-4 bigcount surface (VERDICT r4 next #9): MPI_Count overloads of
+/* MPI-4 bigcount surface: MPI_Count overloads of
  * the count-taking core. A REAL >INT_MAX-element payload moves through
  * MPI_Send_c / MPI_Recv_c (2.2e9 MPI_CHAR = ~2.2 GB — this host has
  * the RAM), and the collective path is exercised with MPI_Allreduce_c.

@@ -1,5 +1,5 @@
 /* MPI-IO views + individual pointers + ordered access; dynamic RMA
- * windows; Alltoallw (VERDICT r4 next #5). References:
+ * windows; Alltoallw. References:
  * ompi/mpi/c/file_set_view.c.in, file_iread.c.in,
  * file_read_ordered.c.in, win_create_dynamic.c.in, win_attach.c.in,
  * alltoallw.c.in. */

@@ -116,4 +116,6 @@ def test_mpirun_env_translation():
     assert env["OMPI_TPU_MCA_mpi_base_coordinator"] == "10.0.0.1:1234"
     assert env["OMPI_TPU_MCA_mpi_base_num_processes"] == "2"
     assert env["OMPI_TPU_MCA_mpi_base_process_id"] == "1"
+    # one controller per host brings up that host's own chips
+    assert "JAX_PLATFORMS" not in env
     assert args.program == ["prog.py"]

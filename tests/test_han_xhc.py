@@ -177,7 +177,7 @@ def test_xhc_bcast_reduce_barrier(xhc_world, rng):
 @pytest.fixture()
 def xhc_auto_world(world, _vars):
     """xhc preferred but NO explicit level list — the ladder must come
-    from synthesized locality (VERDICT r4 next #10)."""
+    from synthesized locality."""
     _vars("coll_xhc_priority", 80)
     return world.dup()
 

@@ -1,7 +1,7 @@
-"""Two-tier (han-style) collectives beyond allreduce — round-2 VERDICT
-missing #5/weak #9: hier bcast / allgather / reduce_scatter_block /
-barrier, and the allreduce cross-tier step as a scattered-chunk
-exchange (psum_scatter over the high groups) instead of gather+sum."""
+"""Two-tier (han-style) collectives beyond allreduce: hier bcast /
+allgather / reduce_scatter_block / barrier, and the allreduce
+cross-tier step as a scattered-chunk exchange (psum_scatter over the
+high groups) instead of gather+sum."""
 import numpy as np
 import pytest
 

@@ -1,4 +1,4 @@
-"""Device-native neighbor collectives (round-2 VERDICT weak #6): cart
+"""Device-native neighbor collectives: cart
 and graph neighbor exchanges keep data on device, lowered to
 edge-colored ppermute waves (topo/neighbor.py). The host NumPy paths
 remain for host buffers; both must agree."""

@@ -14,19 +14,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ompi_tpu.models import transformer as T
 from ompi_tpu.parallel import InGraphComm
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:                                   # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
 def _smap(fn, mesh, in_specs, out_specs):
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def _mesh1d(n, name):
@@ -307,14 +297,9 @@ def test_pp_train_step_single_axis_matches_ref():
     def step(p, i, t):
         return T.pp_train_step(p, (i, t), cfg, 1e-2, pp_comm=pp,
                                n_micro=2)
-    try:
-        smap = jax.shard_map(step, mesh=mesh,
-                             in_specs=(P(), P(), P()),
-                             out_specs=(P(), P()), check_vma=False)
-    except TypeError:
-        smap = jax.shard_map(step, mesh=mesh,
-                             in_specs=(P(), P(), P()),
-                             out_specs=(P(), P()), check_rep=False)
+    smap = jax.shard_map(step, mesh=mesh,
+                         in_specs=(P(), P(), P()),
+                         out_specs=(P(), P()), check_vma=False)
     new_p, loss = jax.jit(smap)(pp_params, *batch)
     assert jnp.allclose(loss, ref_loss, atol=1e-5), (loss, ref_loss)
     # spot-check one stage weight evolved identically to the flat ref
@@ -373,14 +358,9 @@ def test_moe_grads_keep_replicated_params_replicated():
         div = sum(jnp.sum((x - tp.pmean(x)) ** 2) for x in reps)
         return tp.pmean(div)
 
-    try:
-        smap = jax.shard_map(divergence, mesh=mesh,
-                             in_specs=(specs, P(), P()),
-                             out_specs=P(), check_vma=False)
-    except TypeError:
-        smap = jax.shard_map(divergence, mesh=mesh,
-                             in_specs=(specs, P(), P()),
-                             out_specs=P(), check_rep=False)
+    smap = jax.shard_map(divergence, mesh=mesh,
+                         in_specs=(specs, P(), P()),
+                         out_specs=P(), check_vma=False)
     div = jax.jit(smap)(params, toks[:, :-1], toks[:, 1:])
     assert float(div) < 1e-9, float(div)
 
@@ -420,14 +400,9 @@ def test_pp2_train_step_matches_flat_reference():
     def step(p, i, t):
         return T.pp_train_step(p, (i, t), cfg, 1e-2, pp_comm=pp,
                                n_micro=2)
-    try:
-        smap = jax.shard_map(step, mesh=mesh,
-                             in_specs=(spec, P(), P()),
-                             out_specs=(spec, P()), check_vma=False)
-    except TypeError:
-        smap = jax.shard_map(step, mesh=mesh,
-                             in_specs=(spec, P(), P()),
-                             out_specs=(spec, P()), check_rep=False)
+    smap = jax.shard_map(step, mesh=mesh,
+                         in_specs=(spec, P(), P()),
+                         out_specs=(spec, P()), check_vma=False)
     new_p, loss = jax.jit(smap)(pp_params, *batch)
     assert jnp.allclose(loss, ref_loss, atol=1e-5), (loss, ref_loss)
     # layer 0 lives on stage 0 slot 0; layer 1 on stage 1 slot 0
@@ -482,13 +457,8 @@ def test_moe_grads_replicated_on_dedicated_ep_axis():
         div = sum(jnp.sum((x - ep.pmean(x)) ** 2) for x in reps)
         return ep.pmean(div)
 
-    try:
-        smap = jax.shard_map(divergence, mesh=mesh,
-                             in_specs=(spec, P(), P()),
-                             out_specs=P(), check_vma=False)
-    except TypeError:
-        smap = jax.shard_map(divergence, mesh=mesh,
-                             in_specs=(spec, P(), P()),
-                             out_specs=P(), check_rep=False)
+    smap = jax.shard_map(divergence, mesh=mesh,
+                         in_specs=(spec, P(), P()),
+                         out_specs=P(), check_vma=False)
     div = jax.jit(smap)(params, toks[:, :-1], toks[:, 1:])
     assert float(div) < 1e-9, float(div)

@@ -1,6 +1,6 @@
 """Per-rank execution: textbook MPI programs under ``mpirun --per-rank``.
 
-The round-2 wall (VERDICT missing #1): no textbook per-rank MPI program
+The round-2 wall: no textbook per-rank MPI program
 could run — ``rank()`` returned 0 everywhere and nothing moved bytes
 between processes. These tests launch the mpi4py-flavored smoke programs
 in ``tests/perrank_programs/`` as REAL multi-process jobs: ``mpirun

@@ -10,19 +10,9 @@ from ompi_tpu.parallel import InGraphComm
 from ompi_tpu.parallel.moe import init_moe_params, moe_apply
 from ompi_tpu.parallel.pipeline import pipeline_apply
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:                                   # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
 def _smap(fn, mesh, in_specs, out_specs):
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def test_pipeline_matches_sequential(world, rng):

@@ -1,4 +1,4 @@
-"""Root-targeted reduce/gather/scatter lowerings (VERDICT round-2 #3).
+"""Root-targeted reduce/gather/scatter lowerings.
 
 The round-1 aliases (reduce -> allreduce, gather -> allgather) are now
 the latency-regime choice only; above the decision threshold the xla
@@ -112,7 +112,7 @@ def test_reduce_non_sum_falls_back(world, force, rng):
 
 
 def test_distinct_cache_keys_per_root(world, force, rng):
-    """VERDICT done-criterion: distinct executables per root."""
+    """Distinct executables per root."""
     force("coll_xla_gather_algorithm", "binomial")
     n = world.size
     x = world.stack(list(rng.standard_normal((n, 5)).astype(np.float32)))

@@ -1,4 +1,4 @@
-"""Device-native v-collectives (VERDICT round-2 #5).
+"""Device-native v-collectives.
 
 Round 1 padded ragged buffers on the host and returned lists of host
 arrays. Round 2: device inputs are padded on device, the collective
