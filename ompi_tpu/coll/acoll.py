@@ -47,9 +47,8 @@ GENERATION_HINTS: Dict[str, Tuple[int, Optional[int]]] = {
     # host backend (the CI mesh): MEASURED, not conjecture — the
     # round-4 32 MB sweep on the 8-rank CPU mesh put ring_segmented at
     # 4 MB segments ahead of both 1 MB segments and the plain ring
-    # (one-off sweep also covered 256 KB/16 MB, both worse; bench.py's
-    # ab child re-measures the 1 MB/4 MB/unsegmented points every
-    # run). No ladder hint — xhc keeps its locality fallback.
+    # (one-off sweep also covered 256 KB/16 MB, both worse). No ladder
+    # hint — xhc keeps its locality fallback.
     "cpu": (4 << 20, None),
 }
 

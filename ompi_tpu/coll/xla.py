@@ -42,6 +42,7 @@ from ompi_tpu.coll import decision
 from ompi_tpu.coll.framework import coll_framework
 from ompi_tpu.mca import var
 from ompi_tpu.mca.base import Component
+from ompi_tpu.trace import core as _trace
 
 P = jax.sharding.PartitionSpec
 
@@ -82,6 +83,16 @@ class _LruCache(OrderedDict):
             del self[next(iter(self))]
 
 
+def _launch(span: str, fn: Callable, x):
+    """``fn(x)``, the compiled executable's call, as the span ``span``
+    (the caller checks that the ring or the profiler records)."""
+    tok = _trace.begin(span)
+    try:
+        return fn(x)
+    finally:
+        _trace.end(tok)
+
+
 class XlaCollModule:
     def __init__(self, comm):
         self.comm = comm
@@ -102,13 +113,13 @@ class XlaCollModule:
         fn = self._cache.get(key)
         if fn is None:
             # compile misses dominate first-call latency; trace them as
-            # their own spans so a timeline distinguishes "the
-            # collective was slow" from "the collective compiled"
-            from ompi_tpu.trace import core as _trace
+            # their own spans (ring and profiler sink) so a timeline
+            # distinguishes "the collective was slow" from "the
+            # collective compiled"
             tok = (_trace.begin("xla_compile",
                                 cid=getattr(self.comm, "cid", None),
                                 key=str(key[0]))
-                   if _trace.active else None)
+                   if _trace.active or _trace.recording() else None)
             try:
                 fn = build()
                 if lower_args:
@@ -1218,6 +1229,8 @@ class XlaCollModule:
         ep = var.epoch()            # snapshot BEFORE the decision reads
         hit = self._fast.get(fk)
         if hit is not None and hit[0] == ep:
+            if _trace.active or _trace.recording():
+                return _launch(hit[2], hit[1], x)
             return hit[1](x)
         n = self.comm.size
         alg = self._algorithm("allreduce", x.nbytes // max(n, 1),
@@ -1260,7 +1273,12 @@ class XlaCollModule:
             return self._smap(inner, x.ndim, x.ndim)
         fn = self._compiled(
             self._key("allreduce", x, op.uid, n, alg, nseg), build, x)
-        self._fast[fk] = (ep, fn)
+        # the launch span's name carries the algorithm that serves this
+        # key, fixed here so the hit path builds no string
+        span = f"coll.xla.launch:allreduce/{alg}"
+        self._fast[fk] = (ep, fn, span)
+        if _trace.active or _trace.recording():
+            return _launch(span, fn, x)
         return fn(x)
 
     def allreduce_dtype(self, x, op, dt, count: int,

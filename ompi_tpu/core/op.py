@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ompi_tpu.trace import core as _trace
+
 
 _op_counter = itertools.count()
 
@@ -206,11 +208,25 @@ def reduce_local(inbuf, inoutbuf, op: Op):
     ``test/datatype/check_op.sh`` matrix drives to validate the SIMD
     reduction kernels; here it exercises the same combiner the
     collectives use). Functional: returns the combined array."""
-    if not isinstance(op, Op) or op.fn is None:
-        raise TypeError("invalid reduction op")
-    if op.predefined and not op.is_loc:
-        from ompi_tpu.native import native_reduce_local
-        out = native_reduce_local(op.name, inbuf, inoutbuf)
-        if out is not None:           # C++ kernel table (op/avx role)
-            return out
-    return op.fn(inbuf, inoutbuf)      # inoutbuf = inbuf op inoutbuf
+    # the op layer's spans (ring and profiler sink): the whole call,
+    # and the device combiner's dispatch inside it
+    traced = _trace.active or _trace.recording()
+    tok = _trace.begin("op.reduce_local") if traced else None
+    try:
+        if not isinstance(op, Op) or op.fn is None:
+            raise TypeError("invalid reduction op")
+        if op.predefined and not op.is_loc:
+            from ompi_tpu.native import native_reduce_local
+            out = native_reduce_local(op.name, inbuf, inoutbuf)
+            if out is not None:       # C++ kernel table (op/avx role)
+                return out
+        if not traced:
+            return op.fn(inbuf, inoutbuf)  # inoutbuf = inbuf op inoutbuf
+        launch = _trace.begin("op.launch:" + op.name)
+        try:
+            return op.fn(inbuf, inoutbuf)
+        finally:
+            _trace.end(launch)
+    finally:
+        if tok is not None:
+            _trace.end(tok)

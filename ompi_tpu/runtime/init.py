@@ -86,7 +86,7 @@ def compile_cache_dir() -> str:
     or the program's own ``jax_compilation_cache_dir`` — wins untouched;
     otherwise the cache is ``.jax_cache/`` in the checkout, a fixed path
     (the path is part of the cache key) that .gitignore lists. Called
-    by chip_smoke.py and bench.py before their first compile; ``Init``
+    by chip_smoke.py before its first compile; ``Init``
     leaves the process's cache configuration to the program."""
     path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
             or jax.config.jax_compilation_cache_dir)

@@ -24,7 +24,7 @@ ranks' evidence); the summary carries a ``skipped`` count and
 
 Without input files it renders the CURRENT process's ring — the
 in-process escape hatch (call ``ompi_tpu.tools.tracedump.main([...])``
-at the end of a traced program, or rely on ``bench.py --trace``).
+at the end of a traced program).
 
 Usage::
 

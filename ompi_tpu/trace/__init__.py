@@ -11,13 +11,14 @@ in-op time). See docs/OBSERVABILITY.md.
 
 Hot-path contract: everything is gated on ``core.active`` (one module
 attribute read when off — no span allocation, no locking beyond the
-existing SPC path).
+existing SPC path); the library's layer spans also reach a recording
+``jax.profiler`` session, gated on ``active or recording()``.
 """
 from ompi_tpu.trace import attribution, perfetto          # noqa: F401
 from ompi_tpu.trace.core import (                          # noqa: F401
     begin, disable, dump, enable, end, instant, load_dump,
-    maybe_enable_from_var, process_rank, reset, set_process_rank, span,
-    span_dicts, spans, stats, tracing_enabled, wrap_coll_vtable,
+    maybe_enable_from_var, process_rank, recording, reset, set_process_rank,
+    span, span_dicts, spans, stats, tracing_enabled, wrap_coll_vtable,
 )
 from ompi_tpu.trace.ring import Span, SpanRing            # noqa: F401
 

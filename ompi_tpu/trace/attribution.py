@@ -288,8 +288,8 @@ def osc_by_rank(spans: Iterable[SpanLike]) -> Dict[str, Any]:
 def summarize(spans: Iterable[SpanLike],
               stats: Optional[Mapping[str, int]] = None,
               top: int = 5) -> Dict[str, Any]:
-    """The compact, JSON-round-trippable trace summary bench.py
-    attaches to the committed BENCH record: span/drop totals, per-name
+    """The compact, JSON-round-trippable trace summary
+    (``tracedump --format summary``): span/drop totals, per-name
     aggregates, per-rank quant/dequant time (when compression ran),
     and the worst late-arrival attributions."""
     spans = list(spans)

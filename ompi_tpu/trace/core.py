@@ -17,6 +17,13 @@ diagnosed with hand-inserted timers because no timeline existed):
 - **One timebase.** Timestamps are ``time.perf_counter()`` — exactly
   the clock ``tools/mpisync.measure_offset`` measures offsets for, so
   dumps from different controllers align by subtraction.
+- **A profiler sink.** While a ``jax.profiler`` session records, every
+  span is also a TraceMe on the calling thread, on the profiler's own
+  clock beside the device ops. The library's layer spans
+  (``comm.allreduce``, ``coll.xla.launch:allreduce/<alg>``,
+  ``op.reduce_local``, ``op.launch:<op>``) and ``xla_compile`` gate on
+  ``active or recording()``: with no ring and no session, that one
+  check is all they cost.
 
 Counters ride the MPI_T pvar plumbing: ``trace_spans`` (accepted),
 ``trace_dropped`` (ring-full refusals); the attribution layer adds
@@ -30,6 +37,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _TraceMe
+
 from ompi_tpu.mca import pvar as _pvar
 from ompi_tpu.mca import var as _var
 from ompi_tpu.trace.ring import Span, SpanRing
@@ -40,6 +49,10 @@ DEFAULT_CAPACITY = 65536
 # and do nothing else when tracing is off. Rebound (never mutated in
 # place) by enable()/disable(), so readers need no lock.
 active: bool = False
+
+# The profiler sink's gate: True while a jax.profiler session records
+# (one flag read in C++).
+recording = _TraceMe.is_enabled
 
 _ring: Optional[SpanRing] = None
 _ring_lock = threading.Lock()
@@ -136,20 +149,30 @@ def _next_seq(cid: str, name: str) -> int:
 def begin(name: str, cid: Any = None, rank: Optional[int] = None,
           **args) -> tuple:
     """Open a span; returns the token ``end`` consumes. Callers guard
-    with ``if trace.active:`` — this function assumes tracing is on."""
+    with ``if trace.active:`` (or ``active or recording()``): the span
+    goes to the ring if tracing is on, and to the profiler trace if a
+    session records."""
+    tm = None
+    if recording():
+        tm = _TraceMe(name)
+        tm.__enter__()
     scid = None if cid is None else str(cid)
-    seq = None if scid is None else _next_seq(scid, name)
+    seq = None if scid is None or not active else _next_seq(scid, name)
     return (name, time.perf_counter(),
             _process_rank if rank is None else rank,
-            scid, seq, args or None)
+            scid, seq, args or None, active, tm)
 
 
 def end(token: tuple, **extra) -> None:
-    ring = _ring
-    if ring is None or token is None:
+    if token is None:
         return
-    name, t0, rank, cid, seq, args = token
+    name, t0, rank, cid, seq, args, to_ring, tm = token
     dur = time.perf_counter() - t0
+    if tm is not None:
+        tm.__exit__(None, None, None)
+    ring = _ring
+    if ring is None or not to_ring:
+        return
     if extra:
         args = dict(args) if args else {}
         args.update(extra)

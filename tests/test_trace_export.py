@@ -1,7 +1,7 @@
 """Perfetto export schema, mpisync timebase alignment, late-arrival
 attribution on a synthetic skewed barrier, live tracing through the
 coll composer / per-rank interposer, the tracedump CLI, and the
-bench-record summary round trip."""
+summary round trip."""
 import json
 
 import numpy as np
@@ -203,9 +203,8 @@ def test_trace_dump_and_load_roundtrip(tmp_path):
 
 
 def test_bench_trace_summary_roundtrips_json():
-    """The BENCH-record contract: the attached trace summary is
-    machine-readable — json round trip is bit-identical (bench.py
-    asserts the same before committing the record)."""
+    """``attribution.summarize`` is machine-readable: its json round
+    trip is bit-identical."""
     trace_core.enable(capacity=32)
     for s in _skewed_barrier_spans():
         s.ts -= OFFSETS[s.rank]          # one process, one timebase:
@@ -216,7 +215,3 @@ def test_bench_trace_summary_roundtrips_json():
     assert summary["spans"] == 4
     assert summary["by_name"]["coll_barrier"]["count"] == 4
     assert summary["late_arrival_top"][0]["critical_rank"] == LATE_RANK
-
-    import bench
-    bench_summary = bench._trace_summary()   # the committed-record path
-    assert json.loads(json.dumps(bench_summary)) == bench_summary

@@ -85,17 +85,27 @@ def test_instants_record_zero_duration():
 
 
 def test_disabled_hot_path_allocates_no_spans(monkeypatch, world):
-    """Tracing off (the default): the collective/pt2pt gate is ONE
-    attribute read — begin/end/instant must never run."""
+    """Tracing off (the default) and no profiler session: the
+    collective/pt2pt gate is ONE attribute read, the layer spans' one
+    check more — begin/end/instant must never run."""
     def boom(*a, **kw):
         raise AssertionError("tracer touched while disabled")
     monkeypatch.setattr(trace_core, "begin", boom)
     monkeypatch.setattr(trace_core, "instant", boom)
     assert trace_core.active is False
+    assert not trace_core.recording()
 
-    # stacked collective entry (the composer never wrapped the vtable)
+    # stacked collective entry (the composer never wrapped the vtable):
+    # the first call fills coll/xla's memo, the second hits it
     x = world.alloc((2,), np.float32, fill=1.0)
     world.allreduce(x)
+    world.allreduce(x).block_until_ready()
+
+    # MPI_Reduce_local on device operands (the op device combiner)
+    import jax.numpy as jnp
+    import ompi_tpu
+    a = jnp.arange(4, dtype=jnp.float32)
+    ompi_tpu.reduce_local(a, a, ompi_tpu.SUM).block_until_ready()
 
     # per-rank pml entry (loopback engine)
     from ompi_tpu.pml.perrank import PerRankEngine, Router
