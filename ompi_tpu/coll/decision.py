@@ -7,8 +7,7 @@ Re-design of coll/tuned's decision functions
 different: XLA's ``direct`` lowering already emits an ICI-optimal
 schedule, so the fixed table only diverges from ``direct`` where an
 explicit schedule is semantically or structurally better (multi-host
-tiers, very large buffers where the two-phase redscat+allgather shape
-gives XLA a bandwidth-optimal decomposition hint). The *structure* —
+tiers, root-targeted traffic). The *structure* —
 ordered (min_comm_size, min_message_bytes) -> algorithm rules, first
 match from the most specific — mirrors the reference so that operators
 can retune via the dynamic-rules JSON exactly as tuned's dynamic file
@@ -26,11 +25,18 @@ Provenance (which rows are measured):
   reduce/gather/scatter) come from the bench child's A/B matrix on the
   8-rank host mesh and are re-measured every bench run
   (``BENCH_r0*.json`` ab_matrix / reduce_8MB_ab rows).
-- **conjecture**: the TPU-side FIXED_RULES thresholds (root-targeted
-  above 64 KiB, rabenseifner/scatter_allgather above 64 MiB) encode
-  wire-byte arithmetic, not multi-chip measurements — one visible chip
-  cannot A/B an ICI mesh. They are the retuning surface for real
-  hardware via the dynamic-rules JSON, exactly tuned's workflow.
+- **measured on the chip**: the TPU allreduce row, ``direct`` at every
+  size. On a v5e 2x2 host (four chips over ICI) XLA lowers
+  rabenseifner's reduce-scatter to a full all-reduce plus a slice, so
+  the two-phase schedule pays for ``direct``'s one all-reduce and then
+  for an all-gather and an HBM round trip besides; forced A/B pairs at
+  64, 128 and 256 MiB per rank, f32 SUM, all went to ``direct``, by
+  1.6x to 2.0x in time per call.
+- **conjecture**: the other TPU-side FIXED_RULES thresholds
+  (root-targeted above 64 KiB, scatter_allgather bcast above 64 MiB)
+  encode wire-byte arithmetic, not multi-chip measurements. They are
+  the retuning surface for real hardware via the dynamic-rules JSON,
+  exactly tuned's workflow.
 - the multihost ``hier`` rows are structural (two-tier traffic shape),
   exercised for correctness across a real process boundary
   (tests/multiproc_child.py) but not latency-measured.
@@ -42,13 +48,10 @@ from typing import Dict, List, Sequence
 # Fixed decision tables. Every entry must name an algorithm the xla
 # component implements for that collective (see coll/xla.py registry).
 FIXED_RULES: Dict[str, List[Sequence]] = {
-    # Small/latency-bound -> one fused collective (XLA's own schedule);
-    # huge single-host buffers -> explicit redscat+allgather
-    # (Rabenseifner's shape, coll_base_allreduce.c:919-926).
-    "allreduce": [
-        [0, 0, "direct"],
-        [0, 64 << 20, "rabenseifner"],
-    ],
+    # One fused collective (XLA's own schedule) at every size: on ICI
+    # it beats the explicit redscat+allgather shape even at 256 MiB
+    # per rank (measured, see the module docstring).
+    "allreduce": [[0, 0, "direct"]],
     "bcast": [
         [0, 0, "direct"],
         [0, 64 << 20, "scatter_allgather"],

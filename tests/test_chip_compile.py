@@ -100,6 +100,16 @@ def test_allreduce_compiles(xla, mpi, alg, expect):
     assert expect <= _hlo_ops(c)
 
 
+def test_allreduce_default_is_one_all_reduce(xla, mpi):
+    """The default selection, no MCA variable, with the described
+    chips' own platform string reaching the decision table: 256 MB per
+    rank is served by one all-reduce, not a two-phase schedule."""
+    assert xla.comm.devices[0].platform == "tpu"
+    c = _lower(xla, "allreduce", _stacked(xla, PER_RANK), mpi.SUM)
+    ops = _hlo_ops(c)
+    assert "all-reduce" in ops and "all-gather" not in ops
+
+
 @pytest.mark.parametrize("func,expect", [
     ("allgather", {"all-gather"}),
     ("alltoall", {"all-to-all"}),

@@ -108,14 +108,27 @@ def test_barrier_dissemination(mpi, world, alg):
     world.barrier()                            # completes -> pass
 
 
-def test_decision_fixed_table_structure():
-    # last-match-wins over (min_comm_size, min_bytes) thresholds
-    assert decision.decide("allreduce", 8, 64, False) == "direct"
-    assert decision.decide("allreduce", 8, 128 << 20, False) == \
-        "rabenseifner"
-    assert decision.decide("allreduce", 8, 64, True) == "hier"
-    assert decision.decide("bcast", 8, 128 << 20, False) == \
-        "scatter_allgather"
+_RABENSEIFNER_RULES = {"allreduce": {"algorithm_rules": [
+    [0, 0, "direct"], [0, 64 << 20, "rabenseifner"]]}}
+
+
+@pytest.mark.parametrize("func,platform,nbytes,multihost,dyn,want", [
+    ("allreduce", "tpu", 64, False, None, "direct"),
+    ("allreduce", "tpu", 64 << 20, False, None, "direct"),
+    ("allreduce", "tpu", 256 << 20, False, None, "direct"),
+    ("allreduce", "", 128 << 20, False, None, "direct"),
+    ("allreduce", "cpu", 1 << 20, False, None, "rabenseifner"),
+    ("allreduce", "tpu", 64, True, None, "hier"),
+    ("allreduce", "tpu", 256 << 20, False, _RABENSEIFNER_RULES,
+     "rabenseifner"),
+    ("bcast", "", 128 << 20, False, None, "scatter_allgather"),
+])
+def test_decision_fixed_table_structure(func, platform, nbytes, multihost,
+                                        dyn, want):
+    # last-match-wins over (min_comm_size, min_bytes) thresholds; the
+    # dynamic-rules file still overrides the fixed table
+    assert decision.decide(func, 8, nbytes, multihost, dyn,
+                           platform=platform) == want
 
 
 def test_decision_malformed_rules_skipped():
