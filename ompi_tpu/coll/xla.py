@@ -85,7 +85,9 @@ class _LruCache(OrderedDict):
 
 def _launch(span: str, fn: Callable, x):
     """``fn(x)``, the compiled executable's call, as the span ``span``
-    (the caller checks that the ring or the profiler records)."""
+    while the ring or the profiler records."""
+    if not (_trace.active or _trace.recording()):
+        return fn(x)
     tok = _trace.begin(span)
     try:
         return fn(x)
@@ -1229,9 +1231,7 @@ class XlaCollModule:
         ep = var.epoch()            # snapshot BEFORE the decision reads
         hit = self._fast.get(fk)
         if hit is not None and hit[0] == ep:
-            if _trace.active or _trace.recording():
-                return _launch(hit[2], hit[1], x)
-            return hit[1](x)
+            return _launch(hit[2], hit[1], x)
         n = self.comm.size
         alg = self._algorithm("allreduce", x.nbytes // max(n, 1),
                               op.commute)
@@ -1277,9 +1277,7 @@ class XlaCollModule:
         # key, fixed here so the hit path builds no string
         span = f"coll.xla.launch:allreduce/{alg}"
         self._fast[fk] = (ep, fn, span)
-        if _trace.active or _trace.recording():
-            return _launch(span, fn, x)
-        return fn(x)
+        return _launch(span, fn, x)
 
     def allreduce_dtype(self, x, op, dt, count: int,
                         preserve_gaps: bool):
@@ -1376,7 +1374,7 @@ class XlaCollModule:
         ep = var.epoch()            # snapshot BEFORE the decision reads
         hit = self._fast.get(fk)
         if hit is not None and hit[0] == ep:
-            return hit[1](x)
+            return _launch(hit[2], hit[1], x)
         n = self.comm.size
         arith = np.dtype(x.dtype).kind in _ARITH_KINDS
         alg = self._algorithm("bcast", x.nbytes // max(n, 1))
@@ -1416,8 +1414,9 @@ class XlaCollModule:
                     return jax.lax.dynamic_slice_in_dim(g, root, 1, 0)
             return self._smap(inner, x.ndim, x.ndim)
         fn = self._compiled(self._key("bcast", x, root, alg, nseg), build, x)
-        self._fast[fk] = (ep, fn)
-        return fn(x)
+        span = f"coll.xla.launch:bcast/{alg}"
+        self._fast[fk] = (ep, fn, span)
+        return _launch(span, fn, x)
 
     def allgather(self, x):
         x = self._to_mesh(x)
@@ -1425,7 +1424,7 @@ class XlaCollModule:
         ep = var.epoch()            # snapshot BEFORE the decision reads
         hit = self._fast.get(fk)
         if hit is not None and hit[0] == ep:
-            return hit[1](x)
+            return _launch(hit[2], hit[1], x)
         n = self.comm.size
         alg = self._algorithm("allgather", x.nbytes // max(n, 1))
         low = high = None
@@ -1454,8 +1453,9 @@ class XlaCollModule:
                     return g[None]
             return self._smap(inner, x.ndim, x.ndim + 1)
         fn = self._compiled(self._key("allgather", x, alg), build, x)
-        self._fast[fk] = (ep, fn)
-        return fn(x)
+        span = f"coll.xla.launch:allgather/{alg}"
+        self._fast[fk] = (ep, fn, span)
+        return _launch(span, fn, x)
 
     def gather(self, x, root: int):
         """Root-targeted gather: binomial tree toward root (aggregate
@@ -1516,7 +1516,7 @@ class XlaCollModule:
         ep = var.epoch()            # snapshot BEFORE the decision reads
         hit = self._fast.get(fk)
         if hit is not None and hit[0] == ep:
-            return hit[1](x)
+            return _launch(hit[2], hit[1], x)
         n = self.comm.size
         alg = self._algorithm("alltoall", x.nbytes // max(n, 1))
 
@@ -1532,8 +1532,9 @@ class XlaCollModule:
                     return y[None]
             return self._smap(inner, x.ndim, x.ndim)
         fn = self._compiled(self._key("alltoall", x, alg), build, x)
-        self._fast[fk] = (ep, fn)
-        return fn(x)
+        span = f"coll.xla.launch:alltoall/{alg}"
+        self._fast[fk] = (ep, fn, span)
+        return _launch(span, fn, x)
 
     def reduce_scatter_block(self, x, op):
         x = self._to_mesh(x)
@@ -1541,7 +1542,7 @@ class XlaCollModule:
         ep = var.epoch()            # snapshot BEFORE the decision reads
         hit = self._fast.get(fk)
         if hit is not None and hit[0] == ep:
-            return hit[1](x)
+            return _launch(hit[2], hit[1], x)
         n = self.comm.size
         alg = self._algorithm("reduce_scatter_block",
                               x.nbytes // max(n, 1), op.commute)
@@ -1573,8 +1574,9 @@ class XlaCollModule:
             return self._smap(inner, x.ndim, x.ndim - 1)
         fn = self._compiled(
             self._key("reduce_scatter_block", x, op.uid, alg), build, x)
-        self._fast[fk] = (ep, fn)
-        return fn(x)
+        span = f"coll.xla.launch:reduce_scatter_block/{alg}"
+        self._fast[fk] = (ep, fn, span)
+        return _launch(span, fn, x)
 
     def _prefix(self, g, op):
         # Fused prefix kernels only for the *predefined* ops: a user op

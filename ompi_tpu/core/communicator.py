@@ -24,6 +24,7 @@ invocation, SPC counters — then dispatch through the per-communicator
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -66,6 +67,24 @@ def _next_cid() -> int:
     allreduce over available CIDs reduces to a monotone counter."""
     with _cid_lock:
         return next(_cid_counter)
+
+
+def _layer_span(name: str):
+    """The communicator layer's span ``name`` (ring and profiler sink)
+    around a collective method, entry to return on every path; one
+    check when neither is on."""
+    def wrap(method):
+        @functools.wraps(method)
+        def spanned(*args, **kwargs):
+            if not (_trace.active or _trace.recording()):
+                return method(*args, **kwargs)
+            tok = _trace.begin(name)
+            try:
+                return method(*args, **kwargs)
+            finally:
+                _trace.end(tok)
+        return spanned
+    return wrap
 
 
 class Communicator:
@@ -276,72 +295,65 @@ class Communicator:
     # input leading axis = rank, result returned (device path is purely
     # functional; MPI_IN_PLACE is expressed by passing recvbuf as input).
     # ==================================================================
+    @_layer_span("comm.allreduce")
     def allreduce(self, sendbuf, op=op_mod.SUM, *,
                   datatype: Optional[Datatype] = None,
                   count: Optional[int] = None, recvbuf=None):
-        # the communicator layer's span (ring and profiler sink): entry
-        # to return on every path below; one check when neither is on
-        tok = (_trace.begin("comm.allreduce")
-               if _trace.active or _trace.recording() else None)
-        try:
-            in_place = sendbuf is IN_PLACE
-            if in_place:
-                sendbuf = recvbuf   # MPI_IN_PLACE (allreduce.c.in:54,78-79)
-            # sub-eager fast path: contiguous device buffer, no recvbuf —
-            # shape/dtype/op were validated when the key was filled
-            # (validity is a pure function of the key), so a repeat call
-            # is one dict probe plus the selected module's own memo. The
-            # freed-op and ft checks stay per-call; the module re-checks
-            # the var epoch itself.
-            if (datatype is None and recvbuf is None
-                    and getattr(op, "fn", None) is not None
-                    and check_addr(sendbuf) == LOCUS_DEVICE):
-                key = (sendbuf.shape, sendbuf.dtype.name, op.uid)
-                fn = self._subeager.get(key)
-                if fn is None:
-                    self._validate_stacked(sendbuf)
-                    self._validate_op(op)
-                    fn = self._subeager[key] = getattr(
-                        self._coll("allreduce"), "allreduce")
-                    return fn(sendbuf, op)
-                self._check()
-                self._check_ft_coll()
-                spc.record("coll_allreduce", 1)
-                hooks.fire("coll_allreduce", self, {})
+        in_place = sendbuf is IN_PLACE
+        if in_place:
+            sendbuf = recvbuf   # MPI_IN_PLACE (allreduce.c.in:54,78-79)
+        # sub-eager fast path: contiguous device buffer, no recvbuf —
+        # shape/dtype/op were validated when the key was filled
+        # (validity is a pure function of the key), so a repeat call
+        # is one dict probe plus the selected module's own memo. The
+        # freed-op and ft checks stay per-call; the module re-checks
+        # the var epoch itself.
+        if (datatype is None and recvbuf is None
+                and getattr(op, "fn", None) is not None
+                and check_addr(sendbuf) == LOCUS_DEVICE):
+            key = (sendbuf.shape, sendbuf.dtype.name, op.uid)
+            fn = self._subeager.get(key)
+            if fn is None:
+                self._validate_stacked(sendbuf)
+                self._validate_op(op)
+                fn = self._subeager[key] = getattr(
+                    self._coll("allreduce"), "allreduce")
                 return fn(sendbuf, op)
-            self._validate_stacked(sendbuf)
-            self._validate_op(op)
-            # Fused derived-datatype fast path: one
-            # compiled gather->collective->scatter program instead of the
-            # pack/collective/unpack dispatch chain. Device buffers only
-            # (host buffers keep the convertor path); a DISTINCT recvbuf's
-            # gaps cannot come from sendbuf, so that case keeps the
-            # overlay path too.
-            if (datatype is not None and not datatype.is_contiguous
-                    and not datatype.pair and op.fn is not None
-                    and not getattr(op, "is_loc", False)
-                    and (recvbuf is None or in_place)
-                    and check_addr(sendbuf) == LOCUS_DEVICE):
-                mod = self._coll("allreduce")
-                fd = getattr(mod, "allreduce_dtype", None)
-                cnt = (count if count is not None else
-                       sendbuf.shape[-1] // max(datatype.extent, 1))
-                # shape contract: the fused program returns sendbuf's own
-                # shape, so it may only serve exact-fit buffers (last dim
-                # == count*extent) — otherwise the convertor path's
-                # truncated image is the documented result
-                if (fd is not None
-                        and sendbuf.shape[-1] == cnt * datatype.extent):
-                    return fd(sendbuf, op, datatype, cnt, in_place)
-            x, unpack_fn = self._wire(sendbuf, datatype, count)
-            y = self._coll("allreduce").allreduce(x, op)
-            # Unpack into recvbuf (even for IN_PLACE, where recvbuf is the
-            # send buffer): MPI guarantees gap elements outside the
-            # datatype's map are left untouched.
-            return unpack_fn(y, recvbuf)
-        finally:
-            if tok is not None:
-                _trace.end(tok)
+            self._check()
+            self._check_ft_coll()
+            spc.record("coll_allreduce", 1)
+            hooks.fire("coll_allreduce", self, {})
+            return fn(sendbuf, op)
+        self._validate_stacked(sendbuf)
+        self._validate_op(op)
+        # Fused derived-datatype fast path: one
+        # compiled gather->collective->scatter program instead of the
+        # pack/collective/unpack dispatch chain. Device buffers only
+        # (host buffers keep the convertor path); a DISTINCT recvbuf's
+        # gaps cannot come from sendbuf, so that case keeps the
+        # overlay path too.
+        if (datatype is not None and not datatype.is_contiguous
+                and not datatype.pair and op.fn is not None
+                and not getattr(op, "is_loc", False)
+                and (recvbuf is None or in_place)
+                and check_addr(sendbuf) == LOCUS_DEVICE):
+            mod = self._coll("allreduce")
+            fd = getattr(mod, "allreduce_dtype", None)
+            cnt = (count if count is not None else
+                   sendbuf.shape[-1] // max(datatype.extent, 1))
+            # shape contract: the fused program returns sendbuf's own
+            # shape, so it may only serve exact-fit buffers (last dim
+            # == count*extent) — otherwise the convertor path's
+            # truncated image is the documented result
+            if (fd is not None
+                    and sendbuf.shape[-1] == cnt * datatype.extent):
+                return fd(sendbuf, op, datatype, cnt, in_place)
+        x, unpack_fn = self._wire(sendbuf, datatype, count)
+        y = self._coll("allreduce").allreduce(x, op)
+        # Unpack into recvbuf (even for IN_PLACE, where recvbuf is the
+        # send buffer): MPI guarantees gap elements outside the
+        # datatype's map are left untouched.
+        return unpack_fn(y, recvbuf)
 
     def reduce(self, sendbuf, op=op_mod.SUM, root: int = 0, *,
                datatype: Optional[Datatype] = None,
@@ -355,6 +367,7 @@ class Communicator:
         y = self._coll("reduce").reduce(x, op, root)
         return unpack_fn(y, recvbuf)
 
+    @_layer_span("comm.bcast")
     def bcast(self, buf, root: int = 0, *,
               datatype: Optional[Datatype] = None,
               count: Optional[int] = None):
@@ -364,6 +377,7 @@ class Communicator:
         y = self._coll("bcast").bcast(x, root)
         return unpack_fn(y)
 
+    @_layer_span("comm.allgather")
     def allgather(self, sendbuf, *, datatype: Optional[Datatype] = None,
                   count: Optional[int] = None):
         """in (N, *s) -> out (N, N, *s): out[r, j] = rank j's sendbuf."""
@@ -440,6 +454,7 @@ class Communicator:
             return self.put(np.asarray(chunks))
         return jax.device_put(chunks, self.sharding)
 
+    @_layer_span("comm.alltoall")
     def alltoall(self, sendbuf, *, datatype: Optional[Datatype] = None,
                  count: Optional[int] = None):
         """in (N, N, *s) -> out (N, N, *s): out[j, i] = in[i, j]."""
@@ -449,6 +464,7 @@ class Communicator:
         x, _ = self._wire(sendbuf, datatype, count)
         return self._coll("alltoall").alltoall(x)
 
+    @_layer_span("comm.reduce_scatter_block")
     def reduce_scatter_block(self, sendbuf, op=op_mod.SUM, *,
                              datatype: Optional[Datatype] = None,
                              count: Optional[int] = None):

@@ -132,6 +132,15 @@ def test_collective_compiles(xla, mpi, func, expect):
         assert expect <= ops
 
 
+def test_bcast_default_is_scatter_allgather(xla):
+    """Today's default bcast at 256 MB per rank, the decision table's
+    ``scatter_allgather`` row: the root-masked psum_scatter, which
+    v5e:2x2 lowers to a full all-reduce (plus a slice), then an
+    all-gather. A change to that row has to change this test."""
+    c = _lower(xla, "bcast", _stacked(xla, PER_RANK), 0)
+    assert _hlo_ops(c) == {"all-reduce", "all-gather"}
+
+
 def test_root_targeted_reduce_compiles(xla, mpi):
     """The schedule the TPU decision table picks for reduce above
     64 KiB: psum_scatter plus a binomial collect over ppermute."""

@@ -90,6 +90,54 @@ def test_layer_spans_land_in_the_profiler_trace(world, tmp_path):
     assert trace_core.stats()["spans"] == 0
 
 
+def _coll_call(comm, coll, elems):
+    """A call of ``coll`` on ``elems`` f32 per rank, in its stacked
+    layout."""
+    n = comm.size
+    if coll in ("allgather", "bcast"):
+        x = comm.alloc((elems,), np.float32, fill=1.0)
+    else:
+        x = comm.put(np.ones((n, n, elems // n), np.float32))
+    return {"allgather": lambda: comm.allgather(x),
+            "alltoall": lambda: comm.alltoall(x),
+            "bcast": lambda: comm.bcast(x, 0),
+            "reduce_scatter_block":
+                lambda: comm.reduce_scatter_block(x, op_mod.SUM)}[coll]
+
+
+@pytest.fixture(scope="module")
+def quad(world):
+    """A communicator over four of the CPU devices."""
+    return world.split([0 if r < 4 else 1 for r in range(world.size)])[0]
+
+
+@pytest.mark.parametrize("coll", ["allgather", "alltoall", "bcast",
+                                  "reduce_scatter_block"])
+def test_each_collective_writes_its_layer_spans(quad, tmp_path, coll):
+    """Each call writes one ``comm.<coll>`` span with exactly one
+    ``coll.xla.launch:<coll>/<alg>`` inside it: a call that fills
+    coll/xla's memo (its compile inside) and three that hit it."""
+    hit = _coll_call(quad, coll, 8)
+    hit().block_until_ready()
+    miss = _coll_call(quad, coll, 12)
+    with jax.profiler.trace(str(tmp_path)):
+        for run in (miss, hit, hit, hit):
+            with jax.profiler.TraceAnnotation(f"test.call:{coll}"):
+                run().block_until_ready()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    (evs,) = _host_events(path).values()
+    launch = f"coll.xla.launch:{coll}/"
+    assert {n for n, _, _ in evs if not n.startswith(launch)} == {
+        f"test.call:{coll}", f"comm.{coll}"}
+    calls = [e for e in evs if e[0] == f"test.call:{coll}"]
+    assert len(calls) == 4
+    for c in calls:
+        (o,) = [e for e in evs if e[0] == f"comm.{coll}" and _inside(e, c)]
+        (_,) = [e for e in evs if e[0].startswith(launch) and _inside(e, o)]
+    # the launch span names the algorithm that served, on both paths
+    assert len({n for n, _, _ in evs if n.startswith(launch)}) == 1
+
+
 def test_layer_spans_in_the_ring_carry_no_cid(world):
     trace_core.enable(capacity=256)
     _calls(world, n=2)

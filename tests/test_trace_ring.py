@@ -100,6 +100,13 @@ def test_disabled_hot_path_allocates_no_spans(monkeypatch, world):
     x = world.alloc((2,), np.float32, fill=1.0)
     world.allreduce(x)
     world.allreduce(x).block_until_ready()
+    # the other collectives' layer spans, on the memo's miss and hit
+    blocks = world.put(np.ones((world.size, world.size, 2), np.float32))
+    for call in (lambda: world.bcast(x, 0), lambda: world.allgather(x),
+                 lambda: world.alltoall(blocks),
+                 lambda: world.reduce_scatter_block(blocks)):
+        call()
+        call().block_until_ready()
 
     # MPI_Reduce_local on device operands (the op device combiner)
     import jax.numpy as jnp
