@@ -32,11 +32,17 @@ Provenance (which rows are measured):
   for an all-gather and an HBM round trip besides; forced A/B pairs at
   64, 128 and 256 MiB per rank, f32 SUM, all went to ``direct``, by
   1.6x to 2.0x in time per call.
+- **measured on the chip**: the bcast row, ``direct`` (the root-masked
+  psum, one all-reduce) at every size. scatter_allgather's
+  psum_scatter lowers on v5e 2x2 to the same full all-reduce, and its
+  all-gather and loop come on top; forced A/B pairs at 64, 128 and
+  256 MiB per rank, f32 from root 0, all went to ``direct``, 2.14 /
+  3.73 / 6.54 ms against 4.57 / 8.77 / 17.18 ms per call. The ppermute
+  trees lose too at 256 MiB: binomial 14.9 ms, pipeline 26.8 ms.
 - **conjecture**: the other TPU-side FIXED_RULES thresholds
-  (root-targeted above 64 KiB, scatter_allgather bcast above 64 MiB)
-  encode wire-byte arithmetic, not multi-chip measurements. They are
-  the retuning surface for real hardware via the dynamic-rules JSON,
-  exactly tuned's workflow.
+  (root-targeted above 64 KiB) encode wire-byte arithmetic, not
+  multi-chip measurements. They are the retuning surface for real
+  hardware via the dynamic-rules JSON, exactly tuned's workflow.
 - the multihost ``hier`` rows are structural (two-tier traffic shape),
   exercised for correctness across a real process boundary
   (tests/multiproc_child.py) but not latency-measured.
@@ -52,10 +58,10 @@ FIXED_RULES: Dict[str, List[Sequence]] = {
     # it beats the explicit redscat+allgather shape even at 256 MiB
     # per rank (measured, see the module docstring).
     "allreduce": [[0, 0, "direct"]],
-    "bcast": [
-        [0, 0, "direct"],
-        [0, 64 << 20, "scatter_allgather"],
-    ],
+    # The root-masked psum at every size: on ICI it beats
+    # scatter+allgather even at 256 MiB per rank (measured, see the
+    # module docstring).
+    "bcast": [[0, 0, "direct"]],
     "allgather": [[0, 0, "direct"]],
     "alltoall": [[0, 0, "direct"]],
     "reduce_scatter_block": [[0, 0, "direct"]],

@@ -132,12 +132,21 @@ def test_collective_compiles(xla, mpi, func, expect):
         assert expect <= ops
 
 
-def test_bcast_default_is_scatter_allgather(xla):
-    """Today's default bcast at 256 MB per rank, the decision table's
-    ``scatter_allgather`` row: the root-masked psum_scatter, which
-    v5e:2x2 lowers to a full all-reduce (plus a slice), then an
-    all-gather. A change to that row has to change this test."""
+def test_bcast_default_is_one_all_reduce(xla):
+    """The default bcast at 256 MB per rank, with the described chips'
+    own platform string reaching the decision table: the root-masked
+    psum, one all-reduce and no all-gather."""
+    assert xla.comm.devices[0].platform == "tpu"
     c = _lower(xla, "bcast", _stacked(xla, PER_RANK), 0)
+    assert _hlo_ops(c) == {"all-reduce"}
+
+
+def test_bcast_scatter_allgather_compiles(xla):
+    """The pinned two-phase bcast: the root-masked psum_scatter, which
+    v5e:2x2 lowers to a full all-reduce (plus a slice), then an
+    all-gather."""
+    c = _lower(xla, "bcast", _stacked(xla, PER_RANK), 0,
+               coll_xla_bcast_algorithm="scatter_allgather")
     assert _hlo_ops(c) == {"all-reduce", "all-gather"}
 
 

@@ -110,6 +110,8 @@ def test_barrier_dissemination(mpi, world, alg):
 
 _RABENSEIFNER_RULES = {"allreduce": {"algorithm_rules": [
     [0, 0, "direct"], [0, 64 << 20, "rabenseifner"]]}}
+_SCATTER_ALLGATHER_RULES = {"bcast": {"algorithm_rules": [
+    [0, 0, "direct"], [0, 64 << 20, "scatter_allgather"]]}}
 
 
 @pytest.mark.parametrize("func,platform,nbytes,multihost,dyn,want", [
@@ -121,7 +123,11 @@ _RABENSEIFNER_RULES = {"allreduce": {"algorithm_rules": [
     ("allreduce", "tpu", 64, True, None, "hier"),
     ("allreduce", "tpu", 256 << 20, False, _RABENSEIFNER_RULES,
      "rabenseifner"),
-    ("bcast", "", 128 << 20, False, None, "scatter_allgather"),
+    ("bcast", "", 128 << 20, False, None, "direct"),
+    ("bcast", "tpu", 64 << 20, False, None, "direct"),
+    ("bcast", "tpu", 256 << 20, False, None, "direct"),
+    ("bcast", "tpu", 256 << 20, False, _SCATTER_ALLGATHER_RULES,
+     "scatter_allgather"),
 ])
 def test_decision_fixed_table_structure(func, platform, nbytes, multihost,
                                         dyn, want):
