@@ -15,7 +15,7 @@ earlier line (set-up facts, not metrics). The last line of stdout is the
 verdict, ``{"ok": true, "device": {...}}``.
 
 Sizes are the ones users run (BASELINE.json): 8 B and 256 MB f32 per
-rank for the collectives, 64 MB for each explicit algorithm. All data
+rank for the collectives, 64 MB for each pinned algorithm. All data
 holds small integers, so every sum is exact in any order and every check
 is bitwise.
 """
@@ -34,10 +34,9 @@ import numpy as np
 MB = 1 << 20
 F32 = 4
 
-# every coll/xla allreduce lowering a single-host world can run ('hier'
+# every coll/xla allreduce lowering a single-host world runs ('hier'
 # needs a multi-host mesh and demotes to 'direct' without one)
-XLA_ALLREDUCE_ALGORITHMS = ("direct", "ring", "ring_segmented",
-                            "recursive_doubling", "rabenseifner")
+XLA_ALLREDUCE_ALGORITHMS = ("direct",)
 # the root-targeted schedules the TPU decision table picks above 64 KiB
 ROOT_ALGORITHMS = (("reduce", "rabenseifner_root"), ("gather", "binomial"),
                    ("scatter", "binomial"))

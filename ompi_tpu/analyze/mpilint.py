@@ -776,6 +776,8 @@ def render_mcavars(registry: Optional[Dict[str, List[Dict]]] = None) -> str:
         "Set any var via `OMPI_TPU_MCA_<name>` in the environment, the",
         "JSON param file, or `mca.var.var_set` (docs/ANALYSIS.md).",
         "",
+        f"{len(registry)} variables.",
+        "",
         "| Variable | Type | Default | Registered in | Help |",
         "|---|---|---|---|---|",
     ]

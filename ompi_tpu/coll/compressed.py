@@ -15,9 +15,10 @@ holds only compressed executables):
 
 - allreduce: segmented quantized ring (dequant -> reduce -> requant at
   every reduce-scatter hop, lossless code forwarding in the allgather
-  phase — ``XlaCollModule._ring_allreduce_inner(codec=...)``), or the
-  two-tier hier schedule on multihost meshes with only the slow-tier
-  chunk quantized (``_hier_allreduce_inner(codec=...)``).
+  phase — ``_CompressedDevice._ring_allreduce_inner``, this module's
+  own schedule), or the two-tier hier schedule on multihost meshes with
+  only the slow-tier chunk quantized
+  (``XlaCollModule._hier_allreduce_inner(codec=...)``).
 - allgather: quantize once, fused ``all_gather`` of codes + scales,
   per-row dequant.
 - reduce_scatter_block: per-row quantize, ``all_to_all`` of codes,
@@ -92,6 +93,92 @@ class _CompressedDevice(XlaCollModule):
         return lambda buf, _op=op: mod.allreduce(buf, _op)
 
     # -- compressed schedules ------------------------------------------
+    def _ring_allreduce_inner(self, op, n, shape, codec):
+        """Quantized ring (2(n-1) ppermute steps, EQuARX's reduction-hop
+        structure) over the flattened buffer padded to n chunks; the
+        chunk combine is op.fn. The reduce-scatter phase quantizes the
+        outgoing partial sum, moves 1-byte codes + per-block scales, and
+        the receiver dequantizes before the combine — dequant -> reduce
+        -> requant at each hop. The allgather phase quantizes each
+        rank's finished chunk ONCE and forwards the codes losslessly, so
+        broadcast hops add no further error; the owner's row is its own
+        dequantized image so every rank ends bitwise identical."""
+        total = int(np.prod(shape))
+        chunk = -(-total // n)           # ceil
+        perm = [(i, (i + 1) % n) for i in range(n)]
+        cobj, cblock = codec
+
+        def inner(b):                    # block (1, *s)
+            x = b.reshape(-1)
+            x = jnp.pad(x, (0, n * chunk - total))
+            buf = x.reshape(n, chunk)
+            r = jax.lax.axis_index(AXIS)
+
+            def rs_step(buf, t):
+                send_idx = jnp.mod(r - t, n)
+                send = jax.lax.dynamic_index_in_dim(buf, send_idx, 0,
+                                                    keepdims=False)
+                qc, qs = cobj.jnp_quant(send, cblock)
+                qc = jax.lax.ppermute(qc, AXIS, perm=perm)
+                qs = jax.lax.ppermute(qs, AXIS, perm=perm)
+                recvd = cobj.jnp_dequant(qc, qs, chunk, buf.dtype, cblock)
+                tgt = jnp.mod(r - t - 1, n)
+                cur = jax.lax.dynamic_index_in_dim(buf, tgt, 0,
+                                                   keepdims=False)
+                buf = jax.lax.dynamic_update_index_in_dim(
+                    buf, op.fn(cur, recvd), tgt, 0)
+                return buf, None
+
+            buf, _ = jax.lax.scan(rs_step, buf, jnp.arange(n - 1))
+            # rank r now owns the fully reduced chunk (r+1) mod n
+            own = jnp.mod(r + 1, n)
+            cur = jax.lax.dynamic_index_in_dim(buf, own, 0, keepdims=False)
+            qc, qs = cobj.jnp_quant(cur, cblock)
+            # own row = own dequantized image: what the peers see
+            cur_dq = cobj.jnp_dequant(qc, qs, chunk, buf.dtype, cblock)
+            buf = jax.lax.dynamic_update_index_in_dim(buf, cur_dq, own, 0)
+
+            def ag_step(carry, t):
+                buf, qc, qs = carry
+                qc = jax.lax.ppermute(qc, AXIS, perm=perm)
+                qs = jax.lax.ppermute(qs, AXIS, perm=perm)
+                idx = jnp.mod(r - t, n)
+                buf = jax.lax.dynamic_update_index_in_dim(
+                    buf, cobj.jnp_dequant(qc, qs, chunk, buf.dtype,
+                                          cblock), idx, 0)
+                return (buf, qc, qs), None
+
+            (buf, _, _), _ = jax.lax.scan(ag_step, (buf, qc, qs),
+                                          jnp.arange(n - 1))
+            return buf.reshape(-1)[:total].reshape(b.shape)
+        return inner
+
+    def _ring_segmented_allreduce_inner(self, op, n, shape, nseg, codec):
+        """Segmented ring (``coll_base_allreduce.c:345-357,622``): the
+        payload is split into ``nseg`` segments, each running its OWN
+        complete quantized ring chain — the chains share no values, so
+        nothing in the program orders segment s+1's collective-permutes
+        after segment s's combines, and XLA's async scheduler may
+        overlap them."""
+        total = int(np.prod(shape))
+        seglen = -(-total // nseg)
+        ring = self._ring_allreduce_inner(op, n, (seglen,), codec)
+
+        def inner(b):                    # block (1, *s)
+            x = b.reshape(1, -1)
+            x = jnp.pad(x, ((0, 0), (0, nseg * seglen - total)))
+            outs = [ring(x[:, s * seglen:(s + 1) * seglen])
+                    for s in range(nseg)]
+            return jnp.concatenate(outs, axis=1)[:, :total] \
+                      .reshape(b.shape)
+        return inner
+
+    def _nseg(self, chunk_bytes: int) -> int:
+        """Segment count from the ``coll_xla_segsize`` MCA var (the
+        tuned segsize knob); unroll-bounded at 8."""
+        segsize = max(1, int(var.var_get("coll_xla_segsize", 1 << 20)))
+        return max(1, min(8, -(-chunk_bytes // segsize)))
+
     def allreduce_compressed(self, x, op):
         x = self._to_mesh(x)
         n = self.comm.size

@@ -839,7 +839,7 @@ class RankCommunicator:
     def _pipelined_ring_allreduce(self, data: np.ndarray,
                                   op: op_mod.Op) -> np.ndarray:
         """Segment-pipelined ring allreduce for the host tier — the
-        device ``_ring_segmented_allreduce_inner``'s analogue over the
+        compressed device ring's (``coll/compressed``) analogue over the
         byte transport (coll_base_allreduce.c ring: reduce-scatter
         ring then allgather ring). Each rank ends up computing ONE
         chunk's full fold and circulating it, so results are bitwise

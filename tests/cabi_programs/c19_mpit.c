@@ -84,9 +84,9 @@ int main(int argc, char **argv)
     MPI_T_cvar_read(ch, val);
     CHECK(strcmp(val, "auto") == 0, 7);
     /* a tool retunes the library: write, reread, restore */
-    MPI_T_cvar_write(ch, "ring");
+    MPI_T_cvar_write(ch, "hier");
     MPI_T_cvar_read(ch, val);
-    CHECK(strcmp(val, "ring") == 0, 8);
+    CHECK(strcmp(val, "hier") == 0, 8);
     MPI_T_cvar_write(ch, "auto");
     MPI_T_cvar_handle_free(&ch);
 

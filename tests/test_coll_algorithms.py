@@ -1,10 +1,14 @@
-"""Explicit algorithm registry tests (coll/xla + coll/decision).
+"""Algorithm selection tests (coll/xla + coll/decision).
 
-Each explicit schedule (ring, recursive doubling, Rabenseifner, bruck,
-binomial, pairwise, dissemination) must produce the same result as the
-``direct`` fused-XLA lowering — the analogue of the reference validating
-every coll_base algorithm against basic_linear.
+Every collective's default lowering must give exactly what numpy gives,
+on the whole world and on an odd-sized sub-communicator, for the sum,
+non-sum and non-commutative ops — the analogue of the reference
+validating every coll_base algorithm against basic_linear. The pinned
+schedules that remain (``hier``, reduce's ``in_order_binary``) must
+match too, and the decision table must pick what its rows say.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -29,8 +33,7 @@ def _rank_data(world, shape=(5,), dtype=np.float32, seed=0):
     return rows, world.stack(rows)
 
 
-@pytest.mark.parametrize("name", ["ring", "recursive_doubling",
-                                  "rabenseifner", "hier"])
+@pytest.mark.parametrize("name", ["hier"])
 def test_allreduce_algorithms_match_direct(mpi, world, alg, name):
     rows, x = _rank_data(world, (7,))
     alg("allreduce", name)
@@ -39,79 +42,10 @@ def test_allreduce_algorithms_match_direct(mpi, world, alg, name):
     assert np.allclose(y, np.broadcast_to(want, y.shape), atol=1e-4)
 
 
-def test_recursive_doubling_bitwise_identical_across_ranks(mpi, world,
-                                                           alg):
-    # The normalized (lower, higher) combine order must give every rank
-    # the exact same float bits.
-    _, x = _rank_data(world, (16,), seed=3)
-    alg("allreduce", "recursive_doubling")
-    y = np.asarray(world.allreduce(x, mpi.SUM))
-    for r in range(1, world.size):
-        assert np.array_equal(y[0], y[r])
-
-
-def test_allreduce_max_via_recursive_doubling(mpi, world, alg):
-    rows, x = _rank_data(world, (4,), seed=5)
-    alg("allreduce", "recursive_doubling")
-    y = np.asarray(world.allreduce(x, mpi.MAX))
-    assert np.allclose(y[0], np.max(rows, axis=0))
-
-
-@pytest.mark.parametrize("name", ["ring", "bruck", "neighborexchange",
-                                  "two_procs"])
-def test_allgather_algorithms(mpi, world, alg, name):
-    rows, x = _rank_data(world, (3,), seed=1)
-    alg("allgather", name)
-    y = np.asarray(world.allgather(x))
-    want = np.stack(rows)                     # (n, 3)
-    for r in range(world.size):
-        assert np.allclose(y[r], want)
-
-
-@pytest.mark.parametrize("name", ["binomial", "knomial", "chain",
-                                  "pipeline", "scatter_allgather"])
-def test_bcast_algorithms(mpi, world, alg, name):
-    rows, x = _rank_data(world, (6,), seed=2)
-    root = 3
-    alg("bcast", name)
-    y = np.asarray(world.bcast(x, root=root))
-    for r in range(world.size):
-        assert np.allclose(y[r], rows[root], atol=1e-6)
-
-
-def test_alltoall_pairwise(mpi, world, alg):
-    n = world.size
-    rows = [np.arange(n * 2, dtype=np.float32).reshape(n, 2) + 100 * r
-            for r in range(n)]
-    x = world.stack(rows)
-    alg("alltoall", "pairwise")
-    y = np.asarray(world.alltoall(x))
-    for r in range(n):
-        for s in range(n):
-            assert np.allclose(y[r, s], rows[s][r])
-
-
-def test_reduce_scatter_ring(mpi, world, alg):
-    n = world.size
-    rows = [np.random.default_rng(r).standard_normal((n, 3))
-            .astype(np.float32) for r in range(n)]
-    x = world.stack(rows)
-    alg("reduce_scatter_block", "ring")
-    y = np.asarray(world.reduce_scatter_block(x, mpi.SUM))
-    want = np.sum(rows, axis=0)               # (n, 3)
-    for r in range(n):
-        assert np.allclose(y[r], want[r], atol=1e-4)
-
-
-def test_barrier_dissemination(mpi, world, alg):
-    alg("barrier", "dissemination")
-    world.barrier()                            # completes -> pass
-
-
-_RABENSEIFNER_RULES = {"allreduce": {"algorithm_rules": [
-    [0, 0, "direct"], [0, 64 << 20, "rabenseifner"]]}}
-_SCATTER_ALLGATHER_RULES = {"bcast": {"algorithm_rules": [
-    [0, 0, "direct"], [0, 64 << 20, "scatter_allgather"]]}}
+_HIER_RULES = {"allreduce": {"algorithm_rules": [
+    [0, 0, "direct"], [0, 64 << 20, "hier"]]}}
+_BCAST_HIER_RULES = {"bcast": {"algorithm_rules": [
+    [0, 0, "direct"], [0, 64 << 20, "hier"]]}}
 
 
 @pytest.mark.parametrize("func,platform,nbytes,multihost,dyn,want", [
@@ -119,15 +53,13 @@ _SCATTER_ALLGATHER_RULES = {"bcast": {"algorithm_rules": [
     ("allreduce", "tpu", 64 << 20, False, None, "direct"),
     ("allreduce", "tpu", 256 << 20, False, None, "direct"),
     ("allreduce", "", 128 << 20, False, None, "direct"),
-    ("allreduce", "cpu", 1 << 20, False, None, "rabenseifner"),
+    ("allreduce", "cpu", 1 << 20, False, None, "direct"),
     ("allreduce", "tpu", 64, True, None, "hier"),
-    ("allreduce", "tpu", 256 << 20, False, _RABENSEIFNER_RULES,
-     "rabenseifner"),
+    ("allreduce", "tpu", 256 << 20, False, _HIER_RULES, "hier"),
     ("bcast", "", 128 << 20, False, None, "direct"),
     ("bcast", "tpu", 64 << 20, False, None, "direct"),
     ("bcast", "tpu", 256 << 20, False, None, "direct"),
-    ("bcast", "tpu", 256 << 20, False, _SCATTER_ALLGATHER_RULES,
-     "scatter_allgather"),
+    ("bcast", "tpu", 256 << 20, False, _BCAST_HIER_RULES, "hier"),
 ])
 def test_decision_fixed_table_structure(func, platform, nbytes, multihost,
                                         dyn, want):
@@ -138,18 +70,17 @@ def test_decision_fixed_table_structure(func, platform, nbytes, multihost,
 
 
 def test_decision_malformed_rules_skipped():
-    dyn = {"allreduce": {"algorithm_rules": [["0", "0", "ring"],
-                                             [0, 0, "rabenseifner"]]}}
+    dyn = {"allreduce": {"algorithm_rules": [["0", "0", "direct"],
+                                             [0, 0, "hier"]]}}
     # string thresholds are skipped, well-formed rules still apply
-    assert decision.decide("allreduce", 8, 64, False, dyn) == \
-        "rabenseifner"
+    assert decision.decide("allreduce", 8, 64, False, dyn) == "hier"
 
 
 def test_decision_dynamic_rules_override():
-    dyn = {"allgather": {"algorithm_rules": [[0, 0, "ring"],
-                                             [4, 1024, "bruck"]]}}
-    assert decision.decide("allgather", 2, 64, False, dyn) == "ring"
-    assert decision.decide("allgather", 8, 4096, False, dyn) == "bruck"
+    dyn = {"allgather": {"algorithm_rules": [[0, 0, "direct"],
+                                             [4, 1024, "hier"]]}}
+    assert decision.decide("allgather", 2, 64, False, dyn) == "direct"
+    assert decision.decide("allgather", 8, 4096, False, dyn) == "hier"
 
 
 def test_non_commutative_falls_back_to_direct(mpi, world, alg):
@@ -159,196 +90,9 @@ def test_non_commutative_falls_back_to_direct(mpi, world, alg):
     # ordered left fold yields the highest rank's data; a reordering
     # schedule would yield some other rank's.
     f = mpi.op_create(lambda a, b: b, commute=False)
-    alg("allreduce", "ring")
+    alg("allreduce", "hier")
     y = np.asarray(world.allreduce(x, f))
     assert np.allclose(y[0], rows[world.size - 1], atol=1e-6)
-
-
-@pytest.mark.parametrize("root", [0, 5])
-def test_reduce_knomial(mpi, world, alg, root):
-    rows, x = _rank_data(world, (4,), seed=11)
-    alg("reduce", "knomial")
-    y = np.asarray(world.reduce(x, mpi.SUM, root))
-    assert np.allclose(y[root], np.sum(rows, axis=0), atol=1e-4)
-    y2 = np.asarray(world.reduce(x, mpi.MAX, root))
-    assert np.allclose(y2[root], np.max(rows, axis=0))
-
-
-def test_barrier_tree(mpi, world, alg):
-    alg("barrier", "tree")
-    for _ in range(3):
-        world.barrier()
-
-
-def test_neighborexchange_demotes_on_odd_size(mpi, world, alg):
-    """EVEN_ONLY gate: an odd-size sub-communicator silently runs the
-    direct lowering instead."""
-    n = world.size
-    sub = world.split([0] * 3 + [1] * (n - 3))[0]   # size 3
-    alg("allgather", "neighborexchange")
-    rows = [np.full((2,), float(r)) for r in range(3)]
-    y = np.asarray(sub.allgather(sub.stack(rows)))
-    for r in range(3):
-        assert np.allclose(y[r], np.stack(rows))
-
-
-def test_pipeline_bcast_segments(mpi, world, alg):
-    """Pipeline uses multiple segments once the payload passes segsize."""
-    alg("bcast", "pipeline")
-    var.var_set("coll_xla_segsize", 64)
-    try:
-        rows, x = _rank_data(world, (256,), seed=3)
-        y = np.asarray(world.bcast(x, root=1))
-        for r in range(world.size):
-            assert np.allclose(y[r], rows[1], atol=1e-6)
-    finally:
-        var.var_set("coll_xla_segsize", 1 << 20)
-
-
-def test_reduce_scatter_recursive_halving(mpi, world, alg):
-    n = world.size
-    rows = [np.random.default_rng(10 + r).standard_normal((n, 3))
-            .astype(np.float32) for r in range(n)]
-    x = world.stack(rows)
-    alg("reduce_scatter_block", "recursive_halving")
-    y = np.asarray(world.reduce_scatter_block(x, mpi.SUM))
-    want = np.sum(rows, axis=0)               # (n, 3)
-    for r in range(n):
-        assert np.allclose(y[r], want[r], atol=1e-4)
-
-
-def test_reduce_scatter_recursive_halving_max(mpi, world, alg):
-    # a non-sum commutative op through the same halving schedule
-    n = world.size
-    rows = [np.random.default_rng(20 + r).standard_normal((n, 2))
-            .astype(np.float32) for r in range(n)]
-    x = world.stack(rows)
-    alg("reduce_scatter_block", "recursive_halving")
-    y = np.asarray(world.reduce_scatter_block(x, mpi.MAX))
-    want = np.max(rows, axis=0)
-    for r in range(n):
-        assert np.allclose(y[r], want[r])
-
-
-def test_alltoall_bruck(mpi, world, alg):
-    n = world.size
-    rows = [np.arange(n * 2, dtype=np.float32).reshape(n, 2) + 100 * r
-            for r in range(n)]
-    x = world.stack(rows)
-    alg("alltoall", "bruck")
-    y = np.asarray(world.alltoall(x))
-    for r in range(n):
-        for s in range(n):
-            assert np.allclose(y[r, s], rows[s][r])
-
-
-@pytest.mark.parametrize("opname,ref", [("SUM", np.add),
-                                        ("MAX", np.maximum)])
-def test_scan_recursive_doubling(mpi, world, alg, opname, ref):
-    rows, x = _rank_data(world, (6,), seed=31)
-    alg("scan", "recursive_doubling")
-    y = np.asarray(world.scan(x, getattr(mpi, opname)))
-    acc = rows[0].copy()
-    assert np.allclose(y[0], acc, atol=1e-4)
-    for r in range(1, world.size):
-        acc = ref(acc, rows[r])
-        assert np.allclose(y[r], acc, atol=1e-4), r
-
-
-def test_exscan_recursive_doubling(mpi, world, alg):
-    rows, x = _rank_data(world, (4,), seed=32)
-    alg("scan", "recursive_doubling")
-    y = np.asarray(world.exscan(x, mpi.SUM))
-    acc = rows[0].copy()
-    for r in range(1, world.size):
-        assert np.allclose(y[r], acc, atol=1e-4), r
-        acc = acc + rows[r]
-
-
-def test_scan_rd_matches_direct_exactly_ordered(mpi, world, alg):
-    # rd-scan folds the contiguous left range IN FRONT of the local
-    # value, so it is order-preserving: valid for non-commutative
-    # combines (unlike the REORDERING allreduce schedules)
-    rows, x = _rank_data(world, (3,), seed=33)
-    alg("scan", "recursive_doubling")
-    y_rd = np.asarray(world.scan(x, mpi.SUM))
-    alg("scan", "direct")
-    y_dir = np.asarray(world.scan(x, mpi.SUM))
-    assert np.allclose(y_rd, y_dir, atol=1e-5)
-
-
-def test_scan_rd_allowed_for_non_commutative(mpi, world, alg):
-    # rd-scan is ORDER_PRESERVING: unlike the allreduce schedules, a
-    # non-commutative op must NOT demote it — and the ordered result
-    # must match the direct lowering's left fold.
-    f = mpi.op_create(lambda a, b: b, commute=False)   # right-take
-    rows, x = _rank_data(world, (3,), seed=41)
-    alg("scan", "recursive_doubling")
-    y = np.asarray(world.scan(x, f))
-    for r in range(world.size):
-        # left fold of right-take over ranks 0..r = rank r's own data
-        assert np.allclose(y[r], rows[r], atol=1e-6), r
-    assert ("scan", "recursive_doubling") in decision.ORDER_PRESERVING
-
-
-def test_scan_rd_on_odd_size_subcomm(mpi, world, alg):
-    # POW2_EXEMPT: scan's recursive doubling handles any size — an
-    # odd-sized sub-communicator must still run it (allreduce's
-    # same-named schedule stays pow2-only)
-    colors = [0, 0, 0] + [1] * (world.size - 3)
-    sub = world.split(colors)[0]
-    assert sub.size == 3
-    rows = [np.full(4, r + 1, np.float32) for r in range(3)]
-    x = sub.stack(rows)
-    alg("scan", "recursive_doubling")
-    y = np.asarray(sub.scan(x, mpi.SUM))
-    acc = rows[0].copy()
-    assert np.allclose(y[0], acc)
-    for r in range(1, 3):
-        acc = acc + rows[r]
-        assert np.allclose(y[r], acc), r
-
-
-def test_allgather_sparbit(mpi, world, alg):
-    rows, x = _rank_data(world, (3,), seed=21)
-    alg("allgather", "sparbit")
-    y = np.asarray(world.allgather(x))
-    want = np.stack(rows)
-    for r in range(world.size):
-        assert np.allclose(y[r], want, atol=1e-6), r
-
-
-def test_reduce_scatter_butterfly(mpi, world, alg):
-    rows, x = _rank_data(world, (world.size, 4), seed=22)
-    alg("reduce_scatter_block", "butterfly")
-    y = np.asarray(world.reduce_scatter_block(x, mpi.SUM))
-    want = np.sum(rows, axis=0)          # (n, 4): row r -> rank r
-    for r in range(world.size):
-        assert np.allclose(y[r], want[r], atol=1e-4), r
-    ymax = np.asarray(world.reduce_scatter_block(x, mpi.MAX))
-    wmax = np.max(rows, axis=0)
-    for r in range(world.size):
-        assert np.allclose(ymax[r], wmax[r]), r
-
-
-def test_reduce_scatter_butterfly_odd_subcomm(mpi, world, alg):
-    """The registry row butterfly exists for: halving on a NON-power-
-    of-two member count (recursive_halving demotes there)."""
-    n = world.size
-    if n < 3:
-        pytest.skip("needs >= 3 ranks")
-    subs = world.split([0] * 3 + [mpi.UNDEFINED] * (n - 3))
-    sub = subs[0]
-    assert sub is not None and sub.size == 3
-    rng = np.random.default_rng(23)
-    rows = [rng.standard_normal((3, 2)).astype(np.float32) + r
-            for r in range(3)]
-    x = sub.stack(rows)
-    alg("reduce_scatter_block", "butterfly")
-    y = np.asarray(sub.reduce_scatter_block(x, mpi.SUM))
-    want = np.sum(rows, axis=0)
-    for r in range(3):
-        assert np.allclose(y[r], want[r], atol=1e-4), r
 
 
 @pytest.mark.parametrize("root", [0, 3])
@@ -368,3 +112,88 @@ def test_reduce_in_order_binary_non_commutative(mpi, world, alg):
     y = np.asarray(world.reduce(x, f, 0))
     # ordered fold of right-take == the LAST rank's row
     assert np.allclose(y[0], rows[world.size - 1], atol=1e-6)
+
+
+# -- the default lowering of every collective against numpy ----------------
+# (collective, op or dtype, communicator, root). "RIGHT" is a
+# non-commutative right-take op: an ordered fold of it is the last
+# operand, so a reordered combine shows.
+_FOLDS = {"SUM": np.add, "MAX": np.maximum, "PROD": np.multiply,
+          "RIGHT": lambda a, b: b}
+_COMMS = ("world", "sub3")
+_CASES = (
+    [("allreduce", op, c, None)
+     for op in ("SUM", "MAX", "PROD", "RIGHT") for c in _COMMS]
+    + [("reduce_scatter_block", op, c, None)
+       for op in ("SUM", "MAX", "RIGHT") for c in _COMMS]
+    + [("scan", op, c, None) for op in ("SUM", "MAX", "RIGHT")
+       for c in _COMMS]
+    + [("exscan", op, c, None) for op in ("SUM", "RIGHT") for c in _COMMS]
+    + [("reduce", op, "world", root) for op in ("SUM", "MAX")
+       for root in (0, -1)]
+    + [("bcast", "float32", "world", 0), ("bcast", "float32", "world", 3),
+       ("bcast", "float32", "sub3", 1), ("bcast", "bool", "world", 2)]
+    + [(f, None, c, None) for f in ("allgather", "alltoall")
+       for c in _COMMS]
+    + [("barrier", None, c, None) for c in _COMMS]
+)
+
+
+@pytest.fixture(scope="module")
+def sub3(mpi, world):
+    """A 3-rank (odd, non-power-of-two) sub-communicator."""
+    return world.split([0] * 3 + [mpi.UNDEFINED] * (world.size - 3))[0]
+
+
+@pytest.mark.parametrize(
+    "coll,arg,which,root", _CASES,
+    ids=["-".join(str(p) for p in c if p is not None) for c in _CASES])
+def test_default_lowering_matches_numpy(mpi, world, sub3, rng, coll,
+                                        arg, which, root):
+    comm = world if which == "world" else sub3
+    n = comm.size
+    # small integers: every fold (|PROD| <= 2^8) is exact in float32
+    shape = (n, 2) if coll in ("reduce_scatter_block", "alltoall") else (5,)
+    rows = [rng.integers(-2, 3, size=shape).astype(np.float32)
+            for _ in range(n)]
+    if coll == "barrier":
+        for _ in range(3):
+            comm.barrier()
+        return
+    op = None
+    if arg in _FOLDS:
+        op = (mpi.op_create(lambda a, b: b, commute=False)
+              if arg == "RIGHT" else getattr(mpi, arg))
+        fold = functools.partial(functools.reduce, _FOLDS[arg])
+    if arg == "bool":
+        rows = [r > 0 for r in rows]
+    x = comm.stack(rows)
+    if coll == "allreduce":
+        y = np.asarray(comm.allreduce(x, op))
+        want = [fold(rows)] * n
+    elif coll == "reduce":
+        root %= n
+        y = np.asarray(comm.reduce(x, op, root))[root:root + 1]
+        want = [fold(rows)]
+    elif coll == "reduce_scatter_block":
+        y = np.asarray(comm.reduce_scatter_block(x, op))
+        want = [fold([row[r] for row in rows]) for r in range(n)]
+    elif coll == "scan":
+        y = np.asarray(comm.scan(x, op))
+        want = [fold(rows[:r + 1]) for r in range(n)]
+    elif coll == "exscan":
+        # rank 0's exscan result is undefined by MPI
+        y = np.asarray(comm.exscan(x, op))[1:]
+        want = [fold(rows[:r]) for r in range(1, n)]
+    elif coll == "bcast":
+        y = np.asarray(comm.bcast(x, root=root))
+        want = [rows[root]] * n
+    elif coll == "allgather":
+        y = np.asarray(comm.allgather(x))
+        want = [np.stack(rows)] * n
+    else:                                   # alltoall
+        y = np.asarray(comm.alltoall(x))
+        want = [np.stack([rows[s][r] for s in range(n)])
+                for r in range(n)]
+    assert y.dtype == rows[0].dtype
+    assert np.array_equal(y, np.stack(want)), (coll, arg, which)

@@ -180,30 +180,36 @@ def test_auto_threshold_switches(world, tmp_path, rng):
         var.var_set("coll_tuned_dynamic_rules", "")
 
 
-def test_ring_segmented_allreduce(world, force, rng):
-    """Segmented double-buffered ring (coll_base_allreduce.c:345-357):
-    correct at a size that produces multiple segments per chunk, with a
-    small forced segsize."""
-    force("coll_xla_allreduce_algorithm", "ring_segmented")
-    var.var_set("coll_xla_segsize", 256)        # tiny -> several segs
+def _compressed_ring_case(comm, rng, elems, segsize):
+    """The compressed allreduce's segmented quantized ring
+    (coll_base_allreduce.c:345-357 chains, coll/compressed) at a size
+    that splits into several segments with a tiny forced segsize:
+    within the codec's error bound, the same on every rank."""
+    var.var_set("mpi_base_compress", True)
+    var.var_set("mpi_base_compress_min_bytes", 64)
+    var.var_set("coll_xla_segsize", segsize)
+    c = comm.dup()                      # selection sees compression on
     try:
-        n = world.size
-        x = rng.standard_normal((n, 515)).astype(np.float32)  # odd size
-        y = world.allreduce(world.stack(list(x)), MPI.SUM)
-        np.testing.assert_allclose(np.asarray(y)[0], x.sum(0),
-                                   rtol=1e-4, atol=1e-5)
+        n = c.size
+        x = rng.standard_normal((n, elems)).astype(np.float32)
+        y = np.asarray(c.allreduce(c.stack(list(x)), MPI.SUM))
+        dev = c.c_coll["allreduce"].device
+        nsegs = [k[6] for k in dev._cache if k[0] == "c_allreduce"]
+        assert nsegs and min(nsegs) > 1, nsegs
+        ref = x.sum(0, dtype=np.float64)
+        assert np.abs(y[0] - ref).max() <= 0.02 * np.abs(ref).max()
+        for r in range(1, n):
+            assert np.array_equal(y[0], y[r]), r
     finally:
+        c.free()
         var.var_set("coll_xla_segsize", 1 << 20)
+        var.var_set("mpi_base_compress_min_bytes", 4 << 20)
+        var.var_set("mpi_base_compress", False)
 
 
-def test_ring_segmented_non_pow2(comm6, force, rng):
-    force("coll_xla_allreduce_algorithm", "ring_segmented")
-    var.var_set("coll_xla_segsize", 128)
-    try:
-        n = comm6.size
-        x = rng.standard_normal((n, 100)).astype(np.float32)
-        y = comm6.allreduce(comm6.stack(list(x)), MPI.SUM)
-        np.testing.assert_allclose(np.asarray(y)[0], x.sum(0),
-                                   rtol=1e-4, atol=1e-5)
-    finally:
-        var.var_set("coll_xla_segsize", 1 << 20)
+def test_ring_segmented_allreduce(world, rng):
+    _compressed_ring_case(world, rng, 515, 64)   # odd size, 5 segments
+
+
+def test_ring_segmented_non_pow2(comm6, rng):
+    _compressed_ring_case(comm6, rng, 100, 16)   # 5 segments

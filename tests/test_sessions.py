@@ -17,11 +17,10 @@ def test_var_scope_isolation(world):
     communicators see their own values; the global store never changes."""
     base = var.var_get("coll_xla_allreduce_algorithm", "auto")
     with Session() as s1, Session() as s2:
-        s1.var_set("coll_xla_allreduce_algorithm", "ring")
-        s2.var_set("coll_xla_allreduce_algorithm", "recursive_doubling")
-        assert s1.var_get("coll_xla_allreduce_algorithm") == "ring"
-        assert s2.var_get("coll_xla_allreduce_algorithm") == \
-            "recursive_doubling"
+        s1.var_set("coll_xla_allreduce_algorithm", "hier")
+        s2.var_set("coll_xla_allreduce_algorithm", "direct")
+        assert s1.var_get("coll_xla_allreduce_algorithm") == "hier"
+        assert s2.var_get("coll_xla_allreduce_algorithm") == "direct"
         # the global store is untouched
         assert var.var_get("coll_xla_allreduce_algorithm", "auto") == base
 
@@ -37,10 +36,9 @@ def test_var_scope_isolation(world):
         m1 = c1.c_coll["allreduce"].device
         m2 = c2.c_coll["allreduce"].device
         with var.scope(s1.scope):
-            assert m1._algorithm("allreduce", 32, True) == "ring"
+            assert m1._algorithm("allreduce", 32, True) == "hier"
         with var.scope(s2.scope):
-            assert m2._algorithm("allreduce", 32, True) == \
-                "recursive_doubling"
+            assert m2._algorithm("allreduce", 32, True) == "direct"
 
 
 def test_session_var_set_does_not_leak_to_world(world):
@@ -115,7 +113,7 @@ def test_session_scope_reaches_deferred_nbc_rounds(world):
     fused path even though its round executes later from the progress
     engine (the deferred-decision escape found in review)."""
     with Session() as s:
-        s.var_set("coll_xla_allreduce_algorithm", "ring")
+        s.var_set("coll_xla_allreduce_algorithm", "hier")
         c = s.comm_create_from_group(s.group_from_pset("mpi://WORLD"))
         x = c.alloc((1 << 15,), np.float32, fill=1.0)   # > fused_min
         req = c.iallreduce(x, MPI.SUM)
@@ -123,7 +121,7 @@ def test_session_scope_reaches_deferred_nbc_rounds(world):
         np.testing.assert_allclose(np.asarray(req.get())[0],
                                    float(c.size), rtol=1e-5)
         dev = c.c_coll["allreduce"].device
-        assert any(k[0] == "allreduce" and "ring" in k
+        assert any(k[0] == "allreduce" and "hier" in k
                    for k in dev._cache), list(dev._cache)
 
 
@@ -131,14 +129,14 @@ def test_session_bound_handle_uses_session_algorithm(world):
     """allreduce_bind on a SessionCommunicator warms with the
     session's algorithm choice, not the global one."""
     with Session() as s:
-        s.var_set("coll_xla_allreduce_algorithm", "recursive_doubling")
+        s.var_set("coll_xla_allreduce_algorithm", "hier")
         c = s.comm_create_from_group(s.group_from_pset("mpi://WORLD"))
         x = c.alloc((16,), np.float32, fill=2.0)
         h = c.allreduce_bind(x, MPI.SUM)
         np.testing.assert_allclose(np.asarray(h(x))[0], 2.0 * c.size,
                                    rtol=1e-5)
         dev = c.c_coll["allreduce"].device
-        assert any(k[0] == "allreduce" and "recursive_doubling" in k
+        assert any(k[0] == "allreduce" and "hier" in k
                    for k in dev._cache), list(dev._cache)
 
 
