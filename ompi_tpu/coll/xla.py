@@ -16,7 +16,8 @@ lowering is one fused XLA collective, whose ICI schedule XLA picks:
   reference's documented commutativity constraint,
   ``coll_base_allreduce.c:291-294``).
 - allgather      -> ``lax.all_gather``
-- reduce_scatter -> ``lax.psum_scatter(tiled)``
+- reduce_scatter -> ``lax.psum_scatter(tiled)``; a SUM over 1-D blocks
+  of whole 128-lane rows of a 32-bit type -> ``all_to_all`` + local sum.
 - alltoall       -> ``lax.all_to_all``
 - bcast          -> masked ``psum`` (arithmetic dtypes) or
   all_gather+select; root is a compile-time constant.
@@ -856,11 +857,25 @@ class XlaCollModule:
         # hier rsb is the psum lowering: sum ops only
         low, high, alg = self._hier_or_direct(
             alg if op.xla_prim == "sum" else "direct")
+        # A 1-D block (N, c) puts the scatter axis inside the TPU's
+        # memory tile, where XLA serves psum_scatter as a whole
+        # all-reduce plus a slice. all_to_all and a local sum move the
+        # same (n-1)/n bytes: 4.67 device ms a call against 5.21 (and
+        # 5.82 for the native reduce-scatter after its relayout) for f32
+        # at 256 MiB per rank on a v5e 2x2. 8- and 16-bit types keep
+        # psum_scatter, as their relayout into the all-to-all compiles
+        # as straight-line code (5 min for 256 MiB of bf16); so do
+        # ragged rows (unmeasured) and blocks of two or more dims (a
+        # native reduce-scatter with no relayout).
+        c = x.shape[-1]
+        if (alg == "direct" and op.xla_prim == "sum" and x.ndim == 3
+                and c > 0 and c % 128 == 0 and x.dtype.itemsize == 4):
+            alg = "alltoall_sum"
 
         def build():
             if alg == "hier":
                 inner = self._hier_rsb_inner(low, high, x.shape[2:])
-            elif op.xla_prim == "sum":
+            elif alg == "direct" and op.xla_prim == "sum":
                 def inner(b):                   # (1, N, *s) -> (1, *s)
                     return jax.lax.psum_scatter(b[0], AXIS,
                                                 scatter_dimension=0,
@@ -869,7 +884,8 @@ class XlaCollModule:
                 def inner(b):
                     y = jax.lax.all_to_all(b[0], AXIS, split_axis=0,
                                            concat_axis=0, tiled=True)
-                    return op.reduce_tree(y, axis=0)[None]
+                    # jnp.sum/prod widen 32-bit ints where x64 is on
+                    return op.reduce_tree(y, axis=0)[None].astype(y.dtype)
             return self._smap(inner, x.ndim, x.ndim - 1)
         fn = self._compiled(
             self._key("reduce_scatter_block", x, op.uid, alg), build, x)

@@ -69,8 +69,8 @@ class _Spec(jax.ShapeDtypeStruct):
         return math.prod(self.shape) * np.dtype(self.dtype).itemsize
 
 
-def _stacked(mod, *local):
-    return _Spec((mod.comm.size,) + local, jnp.float32,
+def _stacked(mod, *local, dtype=jnp.float32):
+    return _Spec((mod.comm.size,) + local, dtype,
                  sharding=mod.comm.sharding)
 
 
@@ -78,6 +78,15 @@ def _hlo_ops(compiled):
     return set(re.findall(r"\b(all-reduce|all-gather|all-to-all|"
                           r"reduce-scatter|collective-permute)\b",
                           compiled.as_text()))
+
+
+def _entry_ops(compiled):
+    """The opcodes of the compiled program's entry computation (not of
+    the fusions it calls)."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    return set(re.findall(r"\s([a-z][a-z0-9-]*)\(", entry))
 
 
 def _lower(mod, func, *args, **mca):
@@ -111,7 +120,6 @@ def test_allreduce_default_is_one_all_reduce(xla, mpi):
 @pytest.mark.parametrize("func,expect", [
     ("allgather", {"all-gather"}),
     ("alltoall", {"all-to-all"}),
-    ("reduce_scatter_block", {"all-reduce"}),
 ])
 def test_collective_compiles(xla, mpi, func, expect):
     n = xla.comm.size
@@ -119,15 +127,27 @@ def test_collective_compiles(xla, mpi, func, expect):
         args = (_stacked(xla, PER_RANK),)
     else:
         args = (_stacked(xla, n, PER_RANK // n),)
-    if func == "reduce_scatter_block":
-        args += (mpi.SUM,)
-    c = _lower(xla, func, *args)
-    ops = _hlo_ops(c)
-    if func == "reduce_scatter_block":
-        # v5e:2x2 lowers psum_scatter to an all-reduce plus a slice
-        assert ops & {"reduce-scatter", "all-reduce"}
-    else:
-        assert expect <= ops
+    assert expect <= _hlo_ops(_lower(xla, func, *args))
+
+
+@pytest.mark.parametrize("dtype,extra,alltoall", [
+    (jnp.float32, 0, True),
+    (jnp.bfloat16, 0, False),
+    (jnp.float32, 3, False),
+], ids=["f32", "bf16", "f32-ragged"])
+def test_reduce_scatter_block_default_lowering(xla, mpi, dtype, extra,
+                                               alltoall):
+    """The default SUM at 256 MB per rank in 1-D blocks: f32 rows of
+    whole 128-lane tiles are served by one all-to-all and a local sum,
+    and no all-reduce of the whole buffer; bf16 (whose relayout into an
+    all-to-all takes minutes to compile) and rows of any other length
+    keep psum_scatter's all-reduce plus a slice."""
+    n = xla.comm.size
+    c = 256 * MB // np.dtype(dtype).itemsize // n + extra
+    ops = _entry_ops(_lower(xla, "reduce_scatter_block",
+                            _stacked(xla, n, c, dtype=dtype), mpi.SUM))
+    assert ("all-to-all" in ops, "all-reduce" in ops) == (alltoall,
+                                                          not alltoall)
 
 
 def test_bcast_default_is_one_all_reduce(xla):

@@ -9,6 +9,7 @@ match too, and the decision table must pick what its rows say.
 """
 import functools
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -197,3 +198,41 @@ def test_default_lowering_matches_numpy(mpi, world, sub3, rng, coll,
                 for r in range(n)]
     assert y.dtype == rows[0].dtype
     assert np.array_equal(y, np.stack(want)), (coll, arg, which)
+
+
+# -- reduce_scatter_block SUM by block shape and type ---------------------
+@pytest.fixture(scope="module")
+def quad(mpi, world):
+    """A 4-rank sub-communicator, the v5e 2x2 host's size."""
+    return world.split([0 if r < 4 else 1 for r in range(world.size)])[0]
+
+
+@pytest.mark.parametrize("block", [(256,), (200,), (3, 128)],
+                         ids=["1d-lanes", "1d-ragged", "2d"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+def test_reduce_scatter_block_sum_exact(mpi, quad, dtype, block):
+    """``out[r] = sum_i in[i, r]`` bit for bit on four ranks, for a 1-D
+    block of whole 128-lane rows (all_to_all and a local sum for 32-bit
+    types), a ragged 1-D block and a 2-D block: integers small enough
+    that any order of combining is exact in bf16."""
+    n = quad.size
+    rng = np.random.default_rng(28)
+    xh = rng.integers(-8, 9, size=(n, n) + block).astype(np.float32)
+    x = quad.put(jnp.asarray(xh, getattr(jnp, dtype)))
+    y = quad.reduce_scatter_block(x, mpi.SUM)
+    assert y.dtype == x.dtype and y.shape == (n,) + block
+    assert np.array_equal(np.asarray(y, np.float32), xh.sum(axis=0))
+
+
+def test_reduce_scatter_v_lane_multiple_exact(mpi, quad):
+    """MPI_Reduce_scatter whose padded count m is a multiple of 128
+    rides the (N, N, m) block: ragged counts come back exact."""
+    n = quad.size
+    counts = [256, 128, 200, 256]
+    rng = np.random.default_rng(29)
+    xh = rng.integers(-8, 9, size=(n, sum(counts))).astype(np.float32)
+    out = quad.reduce_scatter(quad.put(xh), counts, mpi.SUM)
+    offs = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    for r in range(n):
+        want = xh[:, offs[r]:offs[r] + counts[r]].sum(axis=0)
+        assert np.array_equal(np.asarray(out[r]), want)

@@ -138,6 +138,24 @@ def test_each_collective_writes_its_layer_spans(quad, tmp_path, coll):
     assert len({n for n, _, _ in evs if n.startswith(launch)}) == 1
 
 
+@pytest.mark.parametrize("block,alg", [((256,), "alltoall_sum"),
+                                       ((2, 128), "direct")],
+                         ids=["1d", "2d"])
+def test_reduce_scatter_block_launch_span_names_its_form(quad, tmp_path,
+                                                         block, alg):
+    """A SUM over 1-D f32 blocks of whole 128-lane rows is served by
+    ``alltoall_sum``; a 2-D block keeps ``direct``."""
+    n = quad.size
+    x = quad.put(np.ones((n, n) + block, np.float32))
+    quad.reduce_scatter_block(x, op_mod.SUM).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        quad.reduce_scatter_block(x, op_mod.SUM).block_until_ready()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    (evs,) = _host_events(path).values()
+    assert [n for n, _, _ in evs if n.startswith("coll.")] == [
+        f"coll.xla.launch:reduce_scatter_block/{alg}"]
+
+
 def test_layer_spans_in_the_ring_carry_no_cid(world):
     trace_core.enable(capacity=256)
     _calls(world, n=2)
