@@ -33,8 +33,15 @@ Everything of a cell is data, found by name (``spec.py``):
   where set).
 - A call: ``calls/<call>.py``, with ``setup``, ``make_entries``,
   ``function`` (the entry the window drives), ``inputs``, ``output``,
-  ``reference``, ``roofline_bytes`` and ``served``. Allgather,
-  alltoall, bcast or a persistent allreduce is a new call file.
+  ``reference``, ``roofline_bytes`` and ``served``, and optionally
+  ``complete(y)``, which waits for what ``function`` returned and
+  returns the finished result that ``output`` reads: without it,
+  ``y.block_until_ready()`` (a ``jax.Array``); for a request,
+  ``Request.get``; for a numpy result, the result itself. A call that
+  reuses its output buffer, as a persistent request its recvbuf,
+  returns a copy or a fresh array, since a kept output must outlive
+  the calls after it. Allgather, alltoall, bcast, or a host-buffer,
+  nonblocking or persistent allreduce is a new call file.
 - A metric: ``metrics/<metric>.py`` with ``read(ctx)``, returning the
   number or None where the run has nothing to read, and an entry in
   ``end_to_end`` or ``per_layer`` of ``BENCHMARK.json``.

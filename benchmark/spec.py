@@ -6,6 +6,8 @@ else is a file found from a name in that entry or in the metric lists:
     configs/<config>.json    the deployment (ranks, types, ops, sizes)
     traffic/<traffic>.json   the MPI call, its phases, sizes and cases
     calls/<call>.py          how to drive that call and its reference
+                             (the functions ``run.py`` lists; an optional
+                             ``complete(y)`` finishes a call's result)
     metrics/<metric>.py      ``read(ctx)`` -> a number, or None
 """
 from __future__ import annotations

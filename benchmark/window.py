@@ -55,17 +55,21 @@ def n_blocks(seconds: float, block_seconds: float, phases: int) -> int:
     return max(phases, round(seconds / block_seconds))
 
 
-def _block(fn, entries, keep: Dict[int, int], deadline: float, kept,
-           phase: int, label: Optional[str]) -> int:
-    """Calls until the deadline; returns how many ran. Two loops, so
-    that the untraced window pays nothing for the annotations."""
+def _block(fn, complete, entries, keep: Dict[int, int], deadline: float,
+           kept, phase: int, label: Optional[str]) -> int:
+    """Calls until the deadline; returns how many ran. ``complete``
+    waits for a call's result and returns it finished; that value is
+    what a sampled call keeps. The call's return is bound to ``y``
+    before the wait, so the previous output is released while the
+    device runs and not after the wait. Two loops, so that the
+    untraced window pays nothing for the annotations."""
     clock = time.perf_counter
     n = len(entries)
     i = 0
     if label is None:
         while True:
             y = fn(*entries[i % n])
-            y.block_until_ready()
+            y = complete(y)
             if i in keep:
                 kept.append((phase, keep[i], y))
             i += 1
@@ -75,7 +79,7 @@ def _block(fn, entries, keep: Dict[int, int], deadline: float, kept,
     while True:
         with TraceAnnotation(label):
             y = fn(*entries[i % n])
-            y.block_until_ready()
+            y = complete(y)
         if i in keep:
             kept.append((phase, keep[i], y))
         i += 1
@@ -83,8 +87,8 @@ def _block(fn, entries, keep: Dict[int, int], deadline: float, kept,
             return i
 
 
-def run(fn, phases, entries, plans, seconds: float, block_seconds: float,
-        annotate: bool) -> Window:
+def run(fn, complete, phases, entries, plans, seconds: float,
+        block_seconds: float, annotate: bool) -> Window:
     """``entries[p]`` are the args of phase p's calls, ``plans[p][k]``
     the sampled calls of its k-th block. With ``annotate`` every block
     and every call is a profiler span named ``bench.block:<phase>`` /
@@ -102,11 +106,12 @@ def run(fn, phases, entries, plans, seconds: float, block_seconds: float,
         try:
             if annotate:
                 with TraceAnnotation(f"bench.block:{name}"):
-                    calls = _block(fn, entries[p], keep, deadline, win.kept,
-                                   p, f"bench.call:{name}")
+                    calls = _block(fn, complete, entries[p], keep,
+                                   deadline, win.kept, p,
+                                   f"bench.call:{name}")
             else:
-                calls = _block(fn, entries[p], keep, deadline, win.kept,
-                               p, None)
+                calls = _block(fn, complete, entries[p], keep, deadline,
+                               win.kept, p, None)
         except Exception:                 # noqa: BLE001 — reported as a
             win.error = traceback.format_exc()   # failed call, not raised
             win.blocks.append(Block(p, t0, time.perf_counter(), 0))
